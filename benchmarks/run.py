@@ -25,6 +25,8 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     add_engine_args(ap, granularity=True)
     args, _ = ap.parse_known_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import (fig2_sota, fig3_hierarchical, fig4_savings,
                             fig5_drift, fig6_fidelity, fig7_serve,

@@ -27,7 +27,10 @@ def main():
         out = os.path.join(OUT, name)
         if os.path.exists(out):
             continue
-        env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
+        # the dry-run compiles for placeholder CPU devices: keep it off
+        # any chip this machine holds
+        env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"),
+                   JAX_PLATFORMS="cpu")
         cmd = [sys.executable, "-m", "repro.launch.dryrun",
                "--arch", arch, "--shape", "train_4k", "--out", out,
                "--strategy", strategy]

@@ -503,6 +503,7 @@ def dryrun_command(params: Dict[str, Any], out_path: str) -> list:
 def eval_dryrun(params: Dict[str, Any], context: Dict[str, Any]) -> dict:
     """Lower + compile one (strategy, config) cell in a subprocess and
     score it by roofline step time — the most expensive fidelity."""
+    from repro.exp.executors import cpu_child_env
     from repro.exp.runners import subprocess_timeout
     out_dir = context.get("out_dir") or os.path.join("results", "dryrun_evals")
     os.makedirs(out_dir, exist_ok=True)
@@ -513,8 +514,7 @@ def eval_dryrun(params: Dict[str, Any], context: Dict[str, Any]) -> dict:
                     cfg_tag or "default"])
     out = os.path.join(out_dir, tag + ".json")
     cmd = dryrun_command(params, out)
-    env = dict(os.environ)
-    env["PYTHONPATH"] = context.get("src_path", "src")
+    env = cpu_child_env(PYTHONPATH=context.get("src_path", "src"))
     try:
         r = subprocess.run(cmd, capture_output=True, text=True,
                            timeout=subprocess_timeout(context), env=env)

@@ -226,11 +226,29 @@ class ThreadExecutor(_PoolBackedExecutor):
 _BLAS_LIMIT = None          # keeps the threadpoolctl limiter alive
 
 
+def cpu_child_env(**extra: str) -> Dict[str, str]:
+    """The parent's environment for a child process, held to the CPU.
+
+    A chip belongs to one process at a time, and every child this package
+    starts (dry-run and compile cells, local workers) compiles for
+    placeholder CPU devices or runs host-side units."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.update(extra)
+    return env
+
+
 def _worker_init() -> None:
-    """Pin BLAS to one thread per pool worker: units are tiny (88-point
-    grids), so library-level threading only makes N workers thrash each
-    other's cores.  threadpoolctl works post-fork where env vars can't."""
+    """Hold the worker to the CPU, and pin BLAS to one thread per pool
+    worker: units are tiny (88-point grids), so library-level threading
+    only makes N workers thrash each other's cores.  threadpoolctl works
+    post-fork where env vars can't.
+
+    A forked worker inherits the parent's imported jax, but no initialised
+    backend as long as the parent touched no device before forking."""
     global _BLAS_LIMIT
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    if "jax" in sys.modules:
+        sys.modules["jax"].config.update("jax_platforms", "cpu")
     try:
         from threadpoolctl import threadpool_limits
         _BLAS_LIMIT = threadpool_limits(limits=1)
@@ -292,8 +310,8 @@ class LocalSubprocessTransport(WorkerTransport):
         self.python = python or sys.executable
 
     def spawn(self) -> subprocess.Popen:
-        env = dict(os.environ)
-        env["PYTHONPATH"] = os.pathsep.join(p for p in sys.path if p)
+        env = cpu_child_env(
+            PYTHONPATH=os.pathsep.join(p for p in sys.path if p))
         return subprocess.Popen(
             [self.python, "-m", "repro.exp", "worker",
              "--heartbeat", str(self.heartbeat_s)],

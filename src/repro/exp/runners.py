@@ -35,6 +35,7 @@ from typing import Any, Dict, List, Sequence, Tuple, Union
 from repro.core.objectives import ObjectiveBinding, bind_objective, \
     get_objective
 from repro.exp.engine import EngineStats, ExperimentEngine, WorkUnit
+from repro.exp.executors import cpu_child_env
 
 
 # ---------------------------------------------------------------------------
@@ -354,8 +355,7 @@ def dryrun_runner(kind: str, params: Dict[str, Any],
            "--arch", arch, "--shape", shape, "--out", out]
     if mesh == "multipod":
         cmd.append("--multi-pod")
-    env = dict(os.environ)
-    env["PYTHONPATH"] = context.get("src_path", "src")
+    env = cpu_child_env(PYTHONPATH=context.get("src_path", "src"))
     try:
         r = subprocess.run(cmd, capture_output=True, text=True,
                            timeout=subprocess_timeout(context),

@@ -3,7 +3,9 @@
 One query token per sequence attends to a KV cache of up to 512k positions
 (the ``long_500k`` serve shape): the KV sequence is the innermost sequential
 grid axis, with online-softmax accumulators ((G,D) f32 + (G,1) max/sum) in
-VMEM scratch, GQA folded as G query heads per KV head.
+VMEM scratch, GQA folded as G query heads per KV head.  The per-sequence
+``length`` vector rides in SMEM by scalar prefetch: a ``(1,)`` VMEM block of
+a ``(B,)`` array is only legal for the TPU lowering when B == 1.
 """
 from __future__ import annotations
 
@@ -15,13 +17,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 NEG_INF = -1e30
 
 
 def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
             scale: float, bk: int, n_kb: int):
+    b = pl.program_id(0)
     kb = pl.program_id(2)
 
     @pl.when(kb == 0)
@@ -33,7 +34,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     q = q_ref[0, 0].astype(jnp.float32)             # (G, D)
     k = k_ref[0, 0].astype(jnp.float32)             # (bk, D)
     v = v_ref[0, 0].astype(jnp.float32)             # (bk, D)
-    length = len_ref[0]
+    length = len_ref[b]
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -73,23 +74,27 @@ def decode_attention(q, k, v, length, *, bk: int = 512,
     qg = q.reshape(B, Hkv, G, D)
     length = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (B,))
 
-    out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, bk=bk, n_kb=n_kb),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, Hkv, n_kb),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, kb: (b,)),
-            pl.BlockSpec((1, 1, G, D), lambda b, h, kb: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, kb: (b, h, kb, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, kb: (b, h, kb, 0)),
+            pl.BlockSpec((1, 1, G, D), lambda b, h, kb, _: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, bk, D), lambda b, h, kb, _: (b, h, kb, 0)),
+            pl.BlockSpec((1, 1, bk, D), lambda b, h, kb, _: (b, h, kb, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, kb: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, G, D),
+                               lambda b, h, kb, _: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, D), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+    )
+    out = pl.pallas_call(
+        functools.partial(_kernel, scale=scale, bk=bk, n_kb=n_kb),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(length, qg, k, v)

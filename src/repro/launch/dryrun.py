@@ -80,8 +80,6 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         top = sorted(c.bytes_by_op.items(), key=lambda kv: -kv[1])[:8]
         print("bytes_by_op:", {k: f"{v:.2e}" for k, v in top}, file=err)
         ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):       # jax < 0.5 returns [dict]
-            ca = ca[0] if ca else {}
         print({k: v for k, v in ca.items()
                if k in ("flops", "bytes accessed")}, file=err)
         print(json.dumps(

@@ -10,9 +10,11 @@ import json
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.blocks import ModelOpts
 from repro.models.model import build_model
 from repro.runtime.serve import BatchedServer, Request
@@ -28,6 +30,7 @@ def main() -> None:
     ap.add_argument("--new-tokens", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -35,7 +38,8 @@ def main() -> None:
     if cfg.is_encoder_only:
         raise SystemExit(f"{cfg.name} is encoder-only; no decode path")
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(args.seed))
+    # serve in the config's dtype: float32 masters would double the bytes
+    params = model.init(jax.random.PRNGKey(args.seed), jnp.dtype(cfg.dtype))
     rng = np.random.default_rng(args.seed)
     reqs = [
         Request(rid=i,

@@ -17,6 +17,7 @@ import jax
 
 from repro.configs import get_config
 from repro.data.pipeline import SyntheticLMData
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.blocks import ModelOpts
 from repro.models.model import build_model
 from repro.runtime.train_loop import TrainLoop, TrainLoopConfig
@@ -35,6 +36,7 @@ def main() -> None:
     ap.add_argument("--out", default="runs/train")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -137,8 +137,10 @@ class BatchedServer:
     position, and the argmax over identical logits is deterministic).
 
     ``use_kernel=True`` puts the flash-decode Pallas kernel on the
-    generation path with per-slot ``length`` (dense/moe without a sliding
-    window; greedy tokens are validated against the reference path).
+    generation path with per-slot ``length`` (greedy tokens are validated
+    against the reference path).  Only dense/moe configs without a sliding
+    window can take it; any other config refuses it with ``ValueError``.
+    The decode step donates the KV cache, so one copy is live per step.
     Families with per-slot support: dense / moe (KV caches) and ssm
     (position-free recurrent state, reset per slot on admission);
     hybrid / vlm fall back to an internal lockstep server (``run()`` only).
@@ -160,8 +162,12 @@ class BatchedServer:
         self.continuous = cfg.family in self.SLOT_FAMILIES
         if use_kernel is None:
             use_kernel = opts.use_kernel
-        self.use_kernel = bool(use_kernel and cfg.family in ("dense", "moe")
-                               and not cfg.sliding_window)
+        if use_kernel and (cfg.family not in ("dense", "moe")
+                           or cfg.sliding_window):
+            raise ValueError(
+                f"{cfg.name}: the decode_attention kernel serves dense/moe "
+                "configs without a sliding window; use use_kernel=False")
+        self.use_kernel = bool(use_kernel)
         self._lockstep: Optional[LockstepServer] = None
         if not self.continuous:
             self._lockstep = LockstepServer(
@@ -176,13 +182,11 @@ class BatchedServer:
         self._cursor = np.zeros(self.B, np.int64)   # per-slot prompt cursor
         self._token = np.zeros((self.B, 1), np.int32)
         self._pos = np.zeros(self.B, np.int32)      # per-slot position
-        if cfg.family in ("dense", "moe"):
-            dopts = dataclasses.replace(opts, use_kernel=self.use_kernel)
-        else:
-            dopts = opts
+        dopts = dataclasses.replace(opts, use_kernel=self.use_kernel)
         self._decode = jax.jit(
             lambda p, t, pos, c: model.decode_step(
-                p, {"token": t, "pos": pos}, c, NOSHARD, dopts))
+                p, {"token": t, "pos": pos}, c, NOSHARD, dopts),
+            donate_argnums=3)
 
     # ------------------------------------------------------------------
     # Streaming API

@@ -93,9 +93,11 @@ def test_kernel_path_matches_reference(dense):
 
 def test_kernel_refused_for_sliding_window():
     model, params = _model("gemma3-27b")      # sliding_window set
+    with pytest.raises(ValueError, match="sliding window"):
+        BatchedServer(model, params, batch_size=2, max_seq=64,
+                      opts=OPTS, use_kernel=True)
     srv = BatchedServer(model, params, batch_size=2, max_seq=64,
-                        opts=OPTS, use_kernel=True)
-    assert not srv.use_kernel                 # silently forced off
+                        opts=OPTS, use_kernel=False)
     assert len(srv.run(_reqs(2))) == 2
 
 

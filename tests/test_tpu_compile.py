@@ -1,0 +1,90 @@
+"""The Pallas kernels compile for a TPU v5e at real widths, without a chip.
+
+Each test lowers a kernel with ``interpret=False`` against one chip of a
+described (not attached) ``v5e:2x2`` topology and asserts that the compiled
+program holds the Mosaic kernel (``tpu_custom_call``).  What the chip's
+compiler refuses (unaligned blocks, too much VMEM) fails here; interpret
+mode accepts it.  Nothing runs, so nothing here checks results or times.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and it keeps it until it exits.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.decode_attention import decode_attention
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.ssd_scan import ssd_scan
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back: keep the cache out of these compiles
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_text(fn, args, sharding) -> str:
+    sds = [jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+           for shape, dt in args]
+    return jax.jit(fn).lower(*sds).compile().as_text()
+
+
+# (B, Hq, Hkv, S, D, cache dtype)
+DECODE_CASES = {
+    # qwen1.5-4b behind BatchedServer: 20 heads, MHA, f32 cache
+    "qwen1.5-4b": (4, 20, 20, 2048, 128, jnp.float32),
+    # minitron-8b heads: 32 query heads over 8 KV heads (G=4)
+    "minitron-8b-gqa": (4, 32, 8, 2048, 128, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DECODE_CASES))
+def test_decode_attention_compiles(one_chip, case):
+    B, Hq, Hkv, S, D, dt = DECODE_CASES[case]
+    hlo = _compiled_text(
+        lambda q, k, v, n: decode_attention(q, k, v, n, bk=512,
+                                            interpret=False),
+        [((B, Hq, D), dt), ((B, Hkv, S, D), dt), ((B, Hkv, S, D), dt),
+         ((B,), jnp.int32)], one_chip)
+    assert "tpu_custom_call" in hlo
+
+
+def test_flash_attention_compiles(one_chip):
+    shape = (1, 8, 2048, 128)
+    hlo = _compiled_text(
+        lambda q, k, v: flash_attention(q, k, v, causal=True, bq=128,
+                                        bk=128, interpret=False),
+        [(shape, jnp.bfloat16)] * 3, one_chip)
+    assert "tpu_custom_call" in hlo
+
+
+def test_ssd_scan_compiles(one_chip):
+    # mamba2-130m: 24 heads of 64, state 128, chunk 256
+    B, L, H, P, N = 2, 2048, 24, 64, 128
+    hlo = _compiled_text(
+        lambda x, dt, A, Bm, Cm, D: ssd_scan(x, dt, A, Bm, Cm, D, chunk=256,
+                                             interpret=False),
+        [((B, L, H, P), jnp.bfloat16), ((B, L, H), jnp.float32),
+         ((H,), jnp.float32), ((B, L, N), jnp.bfloat16),
+         ((B, L, N), jnp.bfloat16), ((H,), jnp.bfloat16)], one_chip)
+    assert "tpu_custom_call" in hlo

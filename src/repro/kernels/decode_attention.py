@@ -97,5 +97,6 @@ def decode_attention(q, k, v, length, *, bk: int = 512,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="decode_attention",
     )(length, qg, k, v)
     return out.reshape(B, Hq, D)

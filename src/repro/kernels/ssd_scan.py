@@ -114,6 +114,7 @@ def ssd_scan(x, dt, A, Bm, Cm, D, *, chunk: int = 128,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="ssd_scan",
     )(A.astype(jnp.float32), D.astype(jnp.float32), xh,
       dth[..., None], dth[:, :, None, :], Bm, Cm)
     return y.transpose(0, 2, 1, 3), state

@@ -361,7 +361,15 @@ class Model:
         slot's own occupancy (continuous batching).  Per-slot positions are
         supported for the dense/moe (KV cache) and ssm (position-free
         recurrent state) families.
+
+        Traced under the name scope ``decode_step``, so that its operations
+        carry that name in a profile.
         """
+        with jax.named_scope("decode_step"):
+            return self._decode_step(params, batch, cache, ctx, opts)
+
+    def _decode_step(self, params, batch, cache, ctx: ShardCtx,
+                     opts: ModelOpts):
         cfg = self.cfg
         dtype = jnp.dtype(cfg.dtype)
         params = _precast(params, dtype, self.param_spec(), ctx)

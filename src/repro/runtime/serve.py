@@ -21,6 +21,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.distrib.logical import NOSHARD
 from repro.models.blocks import ModelOpts
@@ -144,6 +145,28 @@ class BatchedServer:
     Families with per-slot support: dense / moe (KV caches) and ssm
     (position-free recurrent state, reset per slot on admission);
     hybrid / vlm fall back to an internal lockstep server (``run()`` only).
+
+    Counters, cumulative since construction and readable by an operator:
+    ``steps`` (decode steps run), ``slot_steps`` (occupied slots summed
+    over those steps: the batch's useful width) and ``prompt_tokens`` (the
+    slot-steps whose input token came from a prompt rather than from the
+    slot's last output).  ``prompt_tokens / slot_steps`` is the share of
+    the decode steps' work spent feeding prompts one token at a time.
+
+    Every ``step()`` that runs a decode step is traced with
+    ``jax.profiler.TraceAnnotation`` spans, which cost under a microsecond
+    each on a TPU v5e host when no profiler is running: ``serve.step``
+    around the call, with the stats ``step`` (``steps`` at entry, the
+    index ``Request.started`` and ``.finished`` hold), ``slot_steps`` and
+    ``prompt_tokens`` as they stand at entry; inside it, in order,
+    ``serve.admit`` (``serve.reset`` around an ssm slot's state reset),
+    ``serve.dispatch`` (the host-to-device copies, the decode step and the
+    argmax, all dispatched asynchronously), ``serve.sync`` (the host
+    blocked until the argmax is back) and ``serve.walk`` (the per-slot
+    bookkeeping).  In a trace viewer, ``serve.sync`` set against the
+    device's ``jit_decode_step`` and ``jit__argmax`` runs shows how long
+    the host waits past the device's work, and ``serve.reset`` shows what
+    a slot's state reset costs the host at admission.
     """
 
     SLOT_FAMILIES = ("dense", "moe", "ssm")
@@ -176,6 +199,8 @@ class BatchedServer:
             return
         self.cache = model.init_cache(batch_size, max_seq, jnp.float32)
         self.steps = 0                     # completed decode steps
+        self.slot_steps = 0                # occupied slots, summed over steps
+        self.prompt_tokens = 0             # slot-steps fed a prompt token
         self.queue: List[Request] = []
         self.active: List[Optional[Request]] = [None] * self.B
         self.results: Dict[int, List[int]] = {}
@@ -183,10 +208,12 @@ class BatchedServer:
         self._token = np.zeros((self.B, 1), np.int32)
         self._pos = np.zeros(self.B, np.int32)      # per-slot position
         dopts = dataclasses.replace(opts, use_kernel=self.use_kernel)
-        self._decode = jax.jit(
-            lambda p, t, pos, c: model.decode_step(
-                p, {"token": t, "pos": pos}, c, NOSHARD, dopts),
-            donate_argnums=3)
+
+        def decode_step(p, t, pos, c):
+            return model.decode_step(p, {"token": t, "pos": pos}, c,
+                                     NOSHARD, dopts)
+
+        self._decode = jax.jit(decode_step, donate_argnums=3)
 
     # ------------------------------------------------------------------
     # Streaming API
@@ -211,36 +238,27 @@ class BatchedServer:
             raise RuntimeError(
                 f"{self.model.cfg.family} serves via the lockstep fallback; "
                 "use run()")
-        self._admit()
-        if not any(a is not None for a in self.active):
+        if not self.queue and not any(a is not None for a in self.active):
             return []
-        logits, self.cache = self._decode(
-            self.params, jnp.asarray(self._token),
-            jnp.asarray(self._pos, jnp.int32), self.cache)
-        nxt = np.asarray(jnp.argmax(logits, -1))
-        self.steps += 1
-        finished: List[Request] = []
-        for i in range(self.B):
-            r = self.active[i]
-            if r is None:
-                continue
-            self._pos[i] += 1
-            self._cursor[i] += 1
-            if self._cursor[i] < len(r.prompt):
-                self._token[i, 0] = r.prompt[self._cursor[i]]  # prompt feed
-            else:
-                t = int(nxt[i])
-                r.output.append(t)
-                self._token[i, 0] = t
-                if len(r.output) >= r.max_new_tokens or \
-                        (self.eos_id is not None and t == self.eos_id):
-                    self._finish(i, finished)
-                    continue
-            if self._pos[i] >= self.S - 1:
-                # this slot's KV budget is exhausted: truncate ONLY this
-                # request (the lockstep loop flushed the whole batch here)
-                self._finish(i, finished)
-        return finished
+        with TraceAnnotation("serve.step", step=self.steps,
+                             slot_steps=self.slot_steps,
+                             prompt_tokens=self.prompt_tokens):
+            with TraceAnnotation("serve.admit"):
+                self._admit()
+            for i, r in enumerate(self.active):
+                if r is not None:
+                    self.slot_steps += 1
+                    self.prompt_tokens += int(self._cursor[i] < len(r.prompt))
+            with TraceAnnotation("serve.dispatch"):
+                logits, self.cache = self._decode(
+                    self.params, jnp.asarray(self._token),
+                    jnp.asarray(self._pos, jnp.int32), self.cache)
+                nxt = jnp.argmax(logits, -1)
+            with TraceAnnotation("serve.sync"):
+                nxt = np.asarray(nxt)
+            self.steps += 1
+            with TraceAnnotation("serve.walk"):
+                return self._walk(nxt)
 
     def drain(self) -> Dict[int, List[int]]:
         """Step until every queued/active request has finished."""
@@ -282,7 +300,35 @@ class BatchedServer:
             # overwritten as it advances — no reset needed.
             return
         # recurrent state carries across occupants: re-zero the slot
-        self.cache = {k: v.at[:, i].set(0) for k, v in self.cache.items()}
+        with TraceAnnotation("serve.reset"):
+            self.cache = {k: v.at[:, i].set(0)
+                          for k, v in self.cache.items()}
+
+    def _walk(self, nxt: np.ndarray) -> List[Request]:
+        """Advance every occupied slot past the step that produced ``nxt``;
+        return the requests that finished on it."""
+        finished: List[Request] = []
+        for i in range(self.B):
+            r = self.active[i]
+            if r is None:
+                continue
+            self._pos[i] += 1
+            self._cursor[i] += 1
+            if self._cursor[i] < len(r.prompt):
+                self._token[i, 0] = r.prompt[self._cursor[i]]  # prompt feed
+            else:
+                t = int(nxt[i])
+                r.output.append(t)
+                self._token[i, 0] = t
+                if len(r.output) >= r.max_new_tokens or \
+                        (self.eos_id is not None and t == self.eos_id):
+                    self._finish(i, finished)
+                    continue
+            if self._pos[i] >= self.S - 1:
+                # this slot's KV budget is exhausted: truncate ONLY this
+                # request (the lockstep loop flushed the whole batch here)
+                self._finish(i, finished)
+        return finished
 
     def _finish(self, i: int, finished: List[Request]) -> None:
         r = self.active[i]

@@ -173,6 +173,98 @@ def test_streaming_api_finish_order_and_bookkeeping(dense):
     assert srv.results[0] == a.output
 
 
+# ---------------------------------------------------------------------------
+# Spans in the profiler's trace and the work counters
+# ---------------------------------------------------------------------------
+STEP_PARTS = ["serve.admit", "serve.dispatch", "serve.sync", "serve.walk"]
+
+
+def _serve_spans(trace_dir):
+    """``(name, start_ns, end_ns, stats)`` of the host's ``serve.*`` events
+    in the newest trace under ``trace_dir``, by start."""
+    import glob
+    import os
+    path = max(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                         recursive=True), key=os.path.getmtime)
+    data = jax.profiler.ProfileData.from_file(path)
+    out = [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+           for plane in data.planes for line in plane.lines
+           for e in line.events if e.name.startswith("serve.")]
+    return sorted(out, key=lambda s: s[1])
+
+
+@pytest.mark.parametrize("fixture", ("dense", "ssm"))
+def test_step_spans_nest_in_order_and_index_steps(fixture, request,
+                                                  tmp_path):
+    """With the profiler on, each decode step is one ``serve.step`` holding
+    admit, dispatch, sync and walk in that order; its ``step`` stat runs
+    consecutively and is the index a request admitted on it records in
+    ``started``; its counter stats are the server's counters at entry."""
+    model, params = request.getfixturevalue(fixture)
+    srv = BatchedServer(model, params, batch_size=2, max_seq=64, opts=OPTS)
+    srv.run([Request(rid=-1, prompt=[1, 2], max_new_tokens=2)])   # compile
+    reqs = [Request(rid=i, prompt=[3 + i] * (2 + i), max_new_tokens=3)
+            for i in range(4)]
+    for r in reqs:
+        srv.submit(r)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    first, entry, admitted = srv.steps, [], []
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    while srv.queue or any(a is not None for a in srv.active):
+        waiting = [r for r in reqs if r.started is None]
+        entry.append((srv.slot_steps, srv.prompt_tokens))
+        srv.step()
+        admitted.append([r for r in waiting if r.started is not None])
+    assert srv.step() == []                      # no decode step, no span
+    jax.profiler.stop_trace()
+
+    spans = _serve_spans(str(tmp_path))
+    steps = [s for s in spans if s[0] == "serve.step"]
+    assert len(steps) == srv.steps - first == len(entry)
+    assert [s[3]["step"] for s in steps] == list(range(first, srv.steps))
+    assert [(s[3]["slot_steps"], s[3]["prompt_tokens"])
+            for s in steps] == entry
+    resets = 0
+    for (_, a, b, stats), new in zip(steps, admitted):
+        inner = [s for s in spans if s[0] != "serve.step"
+                 and a <= s[1] and s[2] <= b]
+        parts = [s for s in inner if s[0] in STEP_PARTS]
+        assert [s[0] for s in parts] == STEP_PARTS
+        assert all(x[2] <= y[1] for x, y in zip(parts, parts[1:]))
+        assert all(r.started == stats["step"] for r in new)
+        admit = parts[0]
+        nested = [s for s in inner if s[0] == "serve.reset"]
+        assert all(admit[1] <= s[1] and s[2] <= admit[2] for s in nested)
+        assert len(nested) == (len(new) if fixture == "ssm" else 0)
+        resets += len(nested)
+    assert sum(map(len, admitted)) == len(reqs)
+    assert resets == (len(reqs) if fixture == "ssm" else 0)
+
+
+def test_work_counters_match_request_lengths(dense):
+    """``slot_steps`` and ``prompt_tokens`` equal what the requests' lengths
+    give, across slot reuse and a request truncated at ``max_seq``."""
+    model, params = dense
+    S = 24
+    srv = BatchedServer(model, params, batch_size=2, max_seq=S, opts=OPTS)
+    reqs = [Request(rid=0, prompt=[5, 6, 7, 8, 9], max_new_tokens=100),
+            Request(rid=1, prompt=[9, 10], max_new_tokens=4),
+            Request(rid=2, prompt=[1, 2, 3, 4], max_new_tokens=3),
+            Request(rid=3, prompt=[11], max_new_tokens=6),
+            Request(rid=4, prompt=[12, 13, 14], max_new_tokens=2)]
+    out = srv.run(reqs)
+    assert len(out[0]) == S - len(reqs[0].prompt)      # truncated
+    assert [len(out[i]) for i in range(1, 5)] == [4, 3, 6, 2]
+    # a request occupies its slot for its prompt, then for each output
+    # token but the last, whose step makes it and frees the slot
+    assert srv.slot_steps == sum(len(r.prompt) + len(r.output) - 1
+                                 for r in reqs)
+    assert srv.slot_steps == sum(r.finished - r.started for r in reqs)
+    assert srv.prompt_tokens == sum(len(r.prompt) for r in reqs)
+    assert srv.slot_steps < 2 * srv.steps               # slots sat empty
+
+
 def test_fallback_family_serves_via_lockstep():
     model, params = _model("zamba2-7b")       # hybrid: no per-slot path
     srv = BatchedServer(model, params, batch_size=2, max_seq=64,

@@ -9,6 +9,10 @@ mode accepts it.  Nothing runs, so nothing here checks results or times.
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and it keeps it until it exits.
 """
+import ast
+import os
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -43,6 +47,22 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _bench_kernel_pattern() -> str:
+    """``KERNEL``, the pattern by which the benchmark finds the decode-
+    attention kernel among a trace's ops (read from its source, which
+    imports modules only the benchmark's path holds)."""
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench", "metrics",
+        "decode_attention_roofline.py")
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    for node in tree.body:
+        if isinstance(node, ast.Assign) and \
+                [t.id for t in node.targets] == ["KERNEL"]:
+            return ast.literal_eval(node.value)
+    raise AssertionError(f"no KERNEL in {path}")
+
+
 def _compiled_text(fn, args, sharding) -> str:
     sds = [jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
            for shape, dt in args]
@@ -67,6 +87,10 @@ def test_decode_attention_compiles(one_chip, case):
         [((B, Hq, D), dt), ((B, Hkv, S, D), dt), ((B, Hkv, S, D), dt),
          ((B,), jnp.int32)], one_chip)
     assert "tpu_custom_call" in hlo
+    # a trace's op names are the instructions' text, as the benchmark sees it
+    kernel = re.compile(_bench_kernel_pattern())
+    ops = [ln.strip().removeprefix("ROOT ") for ln in hlo.splitlines()]
+    assert any(kernel.search(op) and "tpu_custom_call" in op for op in ops)
 
 
 def test_flash_attention_compiles(one_chip):

@@ -20,8 +20,8 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
-            scale: float, bk: int, n_kb: int):
+def _kernel(len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
+            l_ref, *, scale: float, bk: int, n_kb: int):
     b = pl.program_id(0)
     kb = pl.program_id(2)
 
@@ -57,14 +57,17 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=("bk", "interpret"))
-def decode_attention(q, k, v, length, *, bk: int = 512,
+def decode_attention(q, k, v, length, layer=0, *, bk: int = 512,
                      interpret: bool = False):
-    """q: (B,Hq,D) one token; k,v: (B,Hkv,S,D); attends positions < length.
+    """q: (B,Hq,D) one token; k,v: (B,Hkv,S,D), or (L,B,Hkv,S,D) of which
+    slab ``layer`` is read; attends positions < length.
 
     -> (B,Hq,D)
     """
+    if k.ndim == 4:
+        k, v = k[None], v[None]
     B, Hq, D = q.shape
-    _, Hkv, S, _ = k.shape
+    _, _, Hkv, S, _ = k.shape
     G = Hq // Hkv
     bk = min(bk, S)
     assert S % bk == 0
@@ -73,17 +76,20 @@ def decode_attention(q, k, v, length, *, bk: int = 512,
 
     qg = q.reshape(B, Hkv, G, D)
     length = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (B,))
+    layer = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
+    kv_spec = pl.BlockSpec((None, 1, 1, bk, D),
+                           lambda b, h, kb, _, l: (l[0], b, h, kb, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(B, Hkv, n_kb),
         in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, kb, _: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, kb, _: (b, h, kb, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, kb, _: (b, h, kb, 0)),
+            pl.BlockSpec((1, 1, G, D), lambda b, h, kb, *_: (b, h, 0, 0)),
+            kv_spec,
+            kv_spec,
         ],
         out_specs=pl.BlockSpec((1, 1, G, D),
-                               lambda b, h, kb, _: (b, h, 0, 0)),
+                               lambda b, h, kb, *_: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, D), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
@@ -98,5 +104,5 @@ def decode_attention(q, k, v, length, *, bk: int = 512,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="decode_attention",
-    )(length, qg, k, v)
+    )(length, layer, qg, k, v)
     return out.reshape(B, Hq, D)

@@ -41,7 +41,7 @@ def ssd(x, dt, A, Bm, Cm, D, *, chunk=128, interpret=None):
     return _ssd(x, dt, A, Bm, Cm, D, chunk=chunk, interpret=interpret)
 
 
-def decode_attention(q, k, v, length, *, bk=512, interpret=None):
+def decode_attention(q, k, v, length, layer=0, *, bk=512, interpret=None):
     if interpret is None:
         interpret = _default_interpret()
-    return _decode(q, k, v, length, bk=bk, interpret=interpret)
+    return _decode(q, k, v, length, layer, bk=bk, interpret=interpret)

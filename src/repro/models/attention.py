@@ -196,7 +196,7 @@ def decode_mha(
     pos, is_global=True, window: int = 0,
     k_new: Optional[jax.Array] = None, v_new: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """q: (B,1,Hq,D); caches: (B,Sk,Hkv,D); pos = current token position.
+    """q: (B,1,Hq,D); caches: (B,Hkv,Sk,D); pos = current token position.
 
     ``pos`` is either a scalar (lockstep batch: every sequence sits at the
     same position) or a ``(B,)`` vector of per-slot positions (continuous
@@ -205,16 +205,16 @@ def decode_mha(
 
     When ``k_new/v_new`` are given, the caches are treated as holding only
     positions < pos and the current token's K/V enter the softmax as one
-    extra slot — this keeps the cache READ-ONLY inside scan-over-layers
-    bodies (the actual cache write is a single fused in-place
-    dynamic-update-slice after the layer scan; see Model.decode_step).
+    extra slot (``k_new/v_new``: (B,Hkv,1,D)) — this keeps the cache
+    READ-ONLY inside scan-over-layers bodies (the cache is written after
+    the layer scan, in place, by ``write_rows``; see Model.decode_step).
     """
     B, _, Hq, D = q.shape
-    _, Sk, Hkv, _ = k_cache.shape
+    _, Hkv, Sk, _ = k_cache.shape
     G = Hq // Hkv
     scale = 1.0 / math.sqrt(D)
     qg = q.reshape(B, Hkv, G, D)
-    s = jnp.einsum("bkgd,bskd->bkgs", qg, k_cache,
+    s = jnp.einsum("bkgd,bksd->bkgs", qg, k_cache,
                    preferred_element_type=jnp.float32) * scale
     kpos = jnp.arange(Sk)
     posb = jnp.reshape(jnp.asarray(pos), (-1, 1))    # (1,1) | (B,1)
@@ -224,18 +224,43 @@ def decode_mha(
     s = jnp.where(m[:, None, None, :], s, NEG_INF)
     if k_new is not None:
         s_self = jnp.einsum(
-            "bkgd,bskd->bkgs", qg, k_new.astype(q.dtype),
+            "bkgd,bksd->bkgs", qg, k_new.astype(q.dtype),
             preferred_element_type=jnp.float32) * scale      # (B,Hkv,G,1)
         s = jnp.concatenate([s, s_self], axis=-1)
     p = jax.nn.softmax(s, axis=-1).astype(v_cache.dtype)
     if k_new is not None:
-        o = jnp.einsum("bkgs,bskd->bkgd", p[..., :-1], v_cache) + \
-            p[..., -1:] * v_new.astype(v_cache.dtype).reshape(
-                B, Hkv, 1, D)
+        o = jnp.einsum("bkgs,bksd->bkgd", p[..., :-1], v_cache) + \
+            p[..., -1:] * v_new.astype(v_cache.dtype)
         o = o.astype(v_cache.dtype)
     else:
-        o = jnp.einsum("bkgs,bskd->bkgd", p, v_cache)
+        o = jnp.einsum("bkgs,bksd->bkgd", p, v_cache)
     return o.reshape(B, 1, Hq, D)
+
+
+def write_rows(cache: jax.Array, rows: jax.Array, pos, *, at=()):
+    """Write one K or V row per slot into a head-major cache, in place.
+
+    cache: (*lead, B, Hkv, S, D); rows: (*lead[len(at):], B, Hkv, 1, D),
+    written at the leading indices ``at`` (e.g. the layer) and at position
+    ``pos`` — a scalar (lockstep: one update for the whole batch) or ``(B,)``
+    per-slot positions (one update per slot, unrolled).  Every update is a
+    ``dynamic_update_slice`` of the cache itself, which XLA performs in the
+    cache's buffer: nothing of the cache is copied or relaid out.
+    """
+    nb = cache.ndim - 4                       # index of the batch axis
+    rows = rows.astype(cache.dtype).reshape((1,) * len(at) + rows.shape)
+    at = tuple(jnp.asarray(a, jnp.int32) for a in at)
+    zero = jnp.zeros((), jnp.int32)
+    lead = at + (zero,) * (nb - len(at))
+    pos = jnp.asarray(pos, jnp.int32)
+    if pos.ndim == 0:
+        return jax.lax.dynamic_update_slice(
+            cache, rows, lead + (zero, zero, pos, zero))
+    for b in range(cache.shape[nb]):
+        cache = jax.lax.dynamic_update_slice(
+            cache, rows[..., b:b + 1, :, :, :],
+            lead + (jnp.int32(b), zero, pos[b], zero))
+    return cache
 
 
 # ---------------------------------------------------------------------------
@@ -272,44 +297,49 @@ def cross_attention(
 
 def decode_self_attention(
     p, x: jax.Array, k_cache, v_cache, cfg: ArchConfig, ctx: ShardCtx, *,
-    pos, is_global=True, use_kernel: bool = False,
+    pos, is_global=True, use_kernel: bool = False, layer=None,
 ):
-    """One-token decode step; cache stays read-only here.
+    """One-token decode step against a head-major KV cache.
 
     ``pos`` is a scalar (lockstep) or ``(B,)`` per-slot positions
     (continuous batching); rope is applied at each slot's own position.
-    With ``use_kernel`` the softmax runs through the flash-decode Pallas
-    kernel (``repro.kernels.ops.decode_attention``) with per-slot
-    ``length`` — sliding-window configs must stay on the reference path.
 
-    Returns (out, k_new, v_new) — the caller batches the cache write for all
-    layers into one in-place dynamic-update-slice after the layer scan.
+    Reference path (``use_kernel=False``): ``k_cache``/``v_cache`` are this
+    layer's read-only ``(B,Hkv,S,D)`` slabs; returns ``(out, k_row,
+    v_row)``, rows ``(B,Hkv,1,D)`` that the caller writes for all layers
+    after the layer scan.
+
+    Kernel path (``use_kernel=True``): ``k_cache``/``v_cache`` are the whole
+    ``(L,B,Hkv,S,D)`` stacks carried through the layer scan and ``layer``
+    is this layer's index.  Each slot's new row is written at its own
+    position in place, then the flash-decode Pallas kernel
+    (``repro.kernels.ops.decode_attention``) reads the layer's slab straight
+    out of the stack with per-slot ``length = pos + 1``; returns ``(out,
+    k_cache, v_cache)`` with the rows written.  Sliding-window configs must
+    stay on the reference path.
     """
     B = x.shape[0]
     q = project_q(p, x, cfg)                       # (B,1,Hq,D)
     k_new, v_new = project_kv(p, x, cfg)           # (B,1,Hkv,D)
     posv = jnp.broadcast_to(jnp.reshape(jnp.asarray(pos), (-1, 1)), (B, 1))
     q = rope(q, posv, cfg.rope_theta)
-    k_new = rope(k_new, posv, cfg.rope_theta)
+    k_new = rope(k_new, posv, cfg.rope_theta).transpose(0, 2, 1, 3)
+    v_new = v_new.transpose(0, 2, 1, 3)            # (B,Hkv,1,D)
     if use_kernel:
         if cfg.sliding_window:
             raise ValueError(
                 "decode_attention kernel has no sliding-window mask; "
                 "keep use_kernel=False for windowed configs")
         from repro.kernels import ops as kernel_ops
-        posb = posv[:, 0].astype(jnp.int32)                    # (B,)
-        upd = jax.vmap(lambda c, n, p_: jax.lax.dynamic_update_slice_in_dim(
-            c, n, p_, axis=0))
-        k_full = upd(k_cache, k_new.astype(k_cache.dtype), posb)
-        v_full = upd(v_cache, v_new.astype(v_cache.dtype), posb)
+        k_cache = write_rows(k_cache, k_new, pos, at=(layer,))
+        v_cache = write_rows(v_cache, v_new, pos, at=(layer,))
         o = kernel_ops.decode_attention(
             q.astype(k_cache.dtype).reshape(B, cfg.n_heads, cfg.head_dim),
-            k_full.transpose(0, 2, 1, 3), v_full.transpose(0, 2, 1, 3),
-            posb + 1)
+            k_cache, v_cache, posv[:, 0].astype(jnp.int32) + 1, layer)
         o = o.reshape(B, 1, cfg.n_heads, cfg.head_dim)
-    else:
-        o = decode_mha(q, k_cache, v_cache, ctx, pos=pos,
-                       is_global=is_global, window=cfg.sliding_window,
-                       k_new=k_new, v_new=v_new)
+        return out_proj(p, o.astype(x.dtype), cfg), k_cache, v_cache
+    o = decode_mha(q, k_cache, v_cache, ctx, pos=pos,
+                   is_global=is_global, window=cfg.sliding_window,
+                   k_new=k_new, v_new=v_new)
     return (out_proj(p, o.astype(x.dtype), cfg),
             k_new.astype(k_cache.dtype), v_new.astype(v_cache.dtype))

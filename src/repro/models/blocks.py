@@ -72,15 +72,18 @@ def dense_block(p, h, cfg: ArchConfig, ctx: ShardCtx, opts: ModelOpts, *,
 
 def dense_block_decode(p, h, k_cache, v_cache, cfg: ArchConfig,
                        ctx: ShardCtx, *, pos, is_global=True,
-                       use_kernel: bool = False):
-    """One-token step; cache read-only.  Returns (h, k_new, v_new).
+                       use_kernel: bool = False, layer=None):
+    """One-token step.  Returns (h, k, v).
 
-    ``pos`` may be scalar (lockstep) or ``(B,)`` per-slot positions;
-    ``use_kernel`` routes the softmax through the flash-decode kernel.
+    ``pos`` may be scalar (lockstep) or ``(B,)`` per-slot positions.  On
+    the reference path the caches are this layer's read-only slabs and
+    ``k, v`` its new rows; with ``use_kernel`` they are the whole stacks,
+    ``layer`` indexes them, and ``k, v`` are the stacks with this layer's
+    rows written in place (``attention.decode_self_attention``).
     """
     a, k_new, v_new = attn.decode_self_attention(
         p["attn"], rmsnorm(p["ln1"], h), k_cache, v_cache, cfg, ctx,
-        pos=pos, is_global=is_global, use_kernel=use_kernel)
+        pos=pos, is_global=is_global, use_kernel=use_kernel, layer=layer)
     h = h + a
     hn = rmsnorm(p["ln2"], h)
     if cfg.n_experts:

@@ -316,8 +316,8 @@ class Model:
         cfg = self.cfg
         Hkv, Dh = cfg.n_kv_heads, cfg.head_dim
 
-        def kv(*lead):
-            return jnp.zeros(lead + (batch, seq, Hkv, Dh), dtype)
+        def kv(*lead):        # head-major: the order decode_attention reads
+            return jnp.zeros(lead + (batch, Hkv, seq, Dh), dtype)
 
         if cfg.family in ("dense", "moe"):
             return {"k": kv(cfg.n_layers), "v": kv(cfg.n_layers)}
@@ -362,6 +362,14 @@ class Model:
         supported for the dense/moe (KV cache) and ssm (position-free
         recurrent state) families.
 
+        K/V caches are head-major, ``(..., B, Hkv, S, D)`` (``init_cache``),
+        and are read and written where they lie: each new row is an in-place
+        ``dynamic_update_slice`` (``attention.write_rows``).  With
+        ``opts.use_kernel`` (dense/moe) the caches ride in the layer scan's
+        carry, each layer writes its rows before the kernel reads its slab
+        out of the stack; otherwise every layer reads its slab read-only and
+        the rows are written after the scan.
+
         Traced under the name scope ``decode_step``, so that its operations
         carry that name in a profile.
         """
@@ -378,34 +386,35 @@ class Model:
         h = embed(params["embed"], batch["token"], dtype)   # (B,1,D)
         h = ctx.constrain(h, "batch", "seq", "act_embed")
 
-        if cfg.family in ("dense", "moe"):
-            flags = jnp.asarray(self.global_flags())
+        if cfg.family in ("dense", "moe") and opts.use_kernel:
+            # the whole caches ride in the carry: each layer writes its rows
+            # in place and the kernel reads its slab out of the stack
+            def body(carry, xs):
+                hh, kc, vc = carry
+                p_i, flag, layer = xs
+                return B.dense_block_decode(
+                    p_i, hh, kc, vc, cfg, ctx, pos=pos, is_global=flag,
+                    use_kernel=True, layer=layer), None
 
+            (h, kc, vc), _ = jax.lax.scan(
+                body, (h, cache["k"], cache["v"]),
+                (params["layers"], jnp.asarray(self.global_flags()),
+                 jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+            cache = {"k": kc, "v": vc}
+
+        elif cfg.family in ("dense", "moe"):
             def body(hh, xs):
                 p_i, flag, kc, vc = xs
                 hh, kn, vn = B.dense_block_decode(
-                    p_i, hh, kc, vc, cfg, ctx, pos=pos, is_global=flag,
-                    use_kernel=opts.use_kernel)
+                    p_i, hh, kc, vc, cfg, ctx, pos=pos, is_global=flag)
                 return hh, (kn, vn)
 
             h, (kns, vns) = jax.lax.scan(
-                body, h, (params["layers"], flags, cache["k"], cache["v"]))
-            # single fused in-place cache write for all layers
-            if per_slot:
-                # scatter each slot's K/V row at its own position
-                upd = jax.vmap(
-                    lambda c, n, p_: jax.lax.dynamic_update_slice_in_dim(
-                        c, n, p_, axis=1),
-                    in_axes=(1, 1, 0), out_axes=1)
-                cache = {"k": upd(cache["k"], kns, pos),
-                         "v": upd(cache["v"], vns, pos)}
-            else:
-                cache = {
-                    "k": jax.lax.dynamic_update_slice_in_dim(
-                        cache["k"], kns, pos, axis=2),
-                    "v": jax.lax.dynamic_update_slice_in_dim(
-                        cache["v"], vns, pos, axis=2),
-                }
+                body, h, (params["layers"], jnp.asarray(self.global_flags()),
+                          cache["k"], cache["v"]))
+            # every layer's rows written after the scan, in place
+            cache = {"k": attn_mod.write_rows(cache["k"], kns, pos),
+                     "v": attn_mod.write_rows(cache["v"], vns, pos)}
 
         elif cfg.family == "ssm":
             def body(hh, xs):
@@ -444,10 +453,8 @@ class Model:
                  cache["k"], cache["v"]))
             new = {
                 "ssm": cg["ssm"], "conv": cg["conv"],
-                "k": jax.lax.dynamic_update_slice_in_dim(
-                    cache["k"], kns, pos, axis=2),
-                "v": jax.lax.dynamic_update_slice_in_dim(
-                    cache["v"], vns, pos, axis=2),
+                "k": attn_mod.write_rows(cache["k"], kns, pos),
+                "v": attn_mod.write_rows(cache["v"], vns, pos),
             }
             if "rem" in params:
                 h, rc = jax.lax.scan(
@@ -480,10 +487,8 @@ class Model:
                 (params["self"], params["cross"], cache["k"], cache["v"],
                  cache["xk"], cache["xv"]))
             cache = {
-                "k": jax.lax.dynamic_update_slice_in_dim(
-                    cache["k"], kns, pos, axis=3),
-                "v": jax.lax.dynamic_update_slice_in_dim(
-                    cache["v"], vns, pos, axis=3),
+                "k": attn_mod.write_rows(cache["k"], kns, pos),
+                "v": attn_mod.write_rows(cache["v"], vns, pos),
                 "xk": cache["xk"], "xv": cache["xv"]}
         else:
             raise ValueError(f"{cfg.family} has no decode step")
@@ -512,7 +517,8 @@ def _dense_prefill(p, h, cfg, ctx, opts, positions, is_global):
     else:
         from repro.models.layers import mlp
         f = mlp(p["mlp"], hn, cfg, ctx)
-    return h + f, (k, v)
+    # head-major (B,Hkv,S,D), the decode cache's order
+    return h + f, (k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
 
 
 def _mamba_prefill(p, h, cfg, ctx, opts):
@@ -581,7 +587,7 @@ def _precast(params, dtype, spec=None, ctx: ShardCtx = NOSHARD):
 # layout).  "kv_seq" maps to "data" only in the single-sequence long-context
 # strategy (see repro.launch.steps).
 # ---------------------------------------------------------------------------
-KV_AXES = ("layers", "batch", "kv_seq", "kv_heads", "kv_hd")
+KV_AXES = ("layers", "batch", "kv_heads", "kv_seq", "kv_hd")
 SSM_AXES = ("layers", "batch", "ssm_heads", None, "state")
 CONV_AXES = ("layers", "batch", None, "inner")
 

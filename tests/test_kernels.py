@@ -92,6 +92,30 @@ def test_decode_attention_sweep(B, Hq, Hkv, S, D, length, dt):
                                atol=TOL[dt], rtol=TOL[dt])
 
 
+@pytest.mark.parametrize("Hq,Hkv", [(20, 20), (32, 8)])    # MHA, GQA
+def test_decode_attention_reads_layer_of_stack(Hq, Hkv):
+    """The kernel reads layer ``l`` of a stacked ``(L,B,Hkv,S,D)`` cache
+    (the decode step's layer-scan carry) as it reads that slab alone:
+    against the oracle on each layer's slab, with per-slot lengths."""
+    L, B, S, D = 3, 3, 1024, 128
+    ks = jax.random.split(jax.random.PRNGKey(4), 3)
+    q = jax.random.normal(ks[0], (B, Hq, D))
+    k = jax.random.normal(ks[1], (L, B, Hkv, S, D))
+    v = jax.random.normal(ks[2], (L, B, Hkv, S, D))
+    length = jnp.array([1000, 1, 517], jnp.int32)
+    for layer in range(L):
+        out = decode_attention(q, k, v, length, layer, bk=512,
+                               interpret=True)
+        ref = decode_mha_ref(q, k[layer], v[layer],
+                             length=length[:, None, None])
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=TOL[jnp.float32],
+                                   rtol=TOL[jnp.float32])
+        slab = decode_attention(q, k[layer], v[layer], length, bk=512,
+                                interpret=True)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(slab))
+
+
 @settings(max_examples=10, deadline=None)
 @given(st.integers(0, 2 ** 31 - 1))
 def test_attention_is_convex_combination(seed):

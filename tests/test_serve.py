@@ -9,9 +9,11 @@ better — mid-flight admission at correct positions, per-slot
 truncation, recurrent-state reset on slot reuse — exactly where the
 lockstep loop was wrong or wasteful.
 """
+import dataclasses
 import warnings
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -81,7 +83,25 @@ def test_partial_batch_bit_identical(dense):
     assert cont.run(_reqs(2)) == lock.run(_reqs(2))
 
 
+def _staggered(srv, late_at=4):
+    """Serve requests of different lengths, two of them admitted mid-flight
+    (after ``late_at`` steps) into slots that others have freed, so slots
+    sit at different positions and are reused."""
+    for r in _reqs(3, gen=3):
+        srv.submit(r)
+    out = {}
+    for _ in range(late_at):
+        out.update((r.rid, r.output) for r in srv.step())
+    srv.submit(Request(rid=10, prompt=[7, 8, 9, 10, 11], max_new_tokens=7))
+    srv.submit(Request(rid=11, prompt=[12], max_new_tokens=9))
+    return {**out, **srv.drain()}
+
+
 def test_kernel_path_matches_reference(dense):
+    """The kernel path (cache in the layer scan's carry, rows written in
+    place, the kernel reading each layer's slab out of the stack) gives the
+    reference path's greedy tokens: closed batches with slot reuse, and
+    mid-flight admission at per-slot positions."""
     model, params = dense
     ref = BatchedServer(model, params, batch_size=2, max_seq=64,
                         opts=OPTS, use_kernel=False)
@@ -89,6 +109,43 @@ def test_kernel_path_matches_reference(dense):
                         opts=OPTS, use_kernel=True)
     assert ker.use_kernel
     assert ker.run(_reqs(4)) == ref.run(_reqs(4))
+    out = _staggered(ker)
+    assert out == _staggered(ref)
+    assert set(out) == {0, 1, 2, 10, 11}
+    assert [len(out[i]) for i in (10, 11)] == [7, 9]
+
+
+@pytest.mark.parametrize("use_kernel", (False, True))
+def test_prefill_cache_feeds_decode_step(dense, use_kernel):
+    """``Model.prefill`` emits the head-major cache that ``decode_step``
+    reads: padded along its position axis, it continues the sequence (at
+    per-slot positions) as the full prefill does, and it holds the rows
+    that decoding the same tokens one at a time (lockstep) writes."""
+    model, params = dense
+    n, S = 11, 16
+    toks = jax.random.randint(jax.random.PRNGKey(1), (2, n + 1), 0,
+                              model.cfg.vocab)
+    _, pc = model.prefill(params, {"tokens": toks[:, :n]}, opts=OPTS)
+    full, _ = model.prefill(params, {"tokens": toks}, opts=OPTS)
+    opts = dataclasses.replace(OPTS, use_kernel=use_kernel)
+    pad = [(0, 0)] * 3 + [(0, S - n), (0, 0)]
+    cache = {k: jnp.pad(c, pad) for k, c in pc.items()}
+    assert cache["k"].shape == model.init_cache(2, S)["k"].shape
+    lg, _ = model.decode_step(
+        params, {"token": toks[:, n:], "pos": jnp.full((2,), n, jnp.int32)},
+        cache, opts=opts)
+    np.testing.assert_allclose(np.asarray(lg), np.asarray(full),
+                               rtol=1e-2, atol=1e-2)
+    dec = model.init_cache(2, S, jnp.float32)
+    for i in range(n):              # lockstep: one shared (scalar) position
+        _, dec = model.decode_step(
+            params, {"token": toks[:, i:i + 1], "pos": jnp.int32(i)}, dec,
+            opts=opts)
+    for key in ("k", "v"):
+        np.testing.assert_allclose(
+            np.asarray(dec[key][..., :n, :]), np.asarray(pc[key]),
+            rtol=1e-2, atol=1e-2)
+        assert not np.asarray(dec[key][..., n:, :]).any()
 
 
 def test_kernel_refused_for_sliding_window():

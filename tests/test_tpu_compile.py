@@ -10,6 +10,8 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and it keeps it until it exits.
 """
 import ast
+import dataclasses
+import math
 import os
 import re
 
@@ -18,6 +20,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import REGISTRY
+from repro.kernels import ops as kernel_ops
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ssd_scan import ssd_scan
@@ -88,6 +92,67 @@ def test_decode_attention_compiles(one_chip, case):
          ((B,), jnp.int32)], one_chip)
     assert "tpu_custom_call" in hlo
     # a trace's op names are the instructions' text, as the benchmark sees it
+    kernel = re.compile(_bench_kernel_pattern())
+    ops = [ln.strip().removeprefix("ROOT ") for ln in hlo.splitlines()]
+    assert any(kernel.search(op) and "tpu_custom_call" in op for op in ops)
+
+
+# The served decode step may hold the cache, or anything as large as one
+# layer's K slab, only as these: no copy, transpose, slice or fusion of it.
+IN_PLACE_OPS = {"parameter", "get-tuple-element", "tuple", "while",
+                "dynamic-update-slice"}
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%(\S+) = (.+?) ([a-z][a-z0-9-]*)\(")
+_F32 = re.compile(r"f32\[([0-9,]*)\]")
+
+
+def _cache_sized(hlo: str, elements: int):
+    """``(name, opcode)`` of each instruction, in any computation of the
+    compiled text, whose result holds a float32 array of ``elements`` or
+    more (the cache's dtype; the bf16 weights are larger and are not
+    looked at)."""
+    out = []
+    for line in hlo.splitlines():
+        m = _INSTR.match(line)
+        if m and any(math.prod(int(d) for d in dims.split(",") if d)
+                     >= elements for dims in _F32.findall(m.group(2))):
+            out.append((m.group(1), m.group(3)))
+    return out
+
+
+# the benchmark's cells: (batch, max_seq) of qwen1.5-4b.chat / .summarize
+SERVED = {"chat": (8, 512), "summarize": (4, 1024)}
+
+
+@pytest.mark.parametrize("cell", sorted(SERVED))
+def test_served_decode_step_reads_and_writes_cache_in_place(
+        one_chip, monkeypatch, cell):
+    """``BatchedServer``'s jitted decode step at qwen1.5-4b widths (depth
+    cut to 2 layers), kernel on, per-slot positions, float32 cache: the
+    cache and its layer slabs appear only as parameters, loop plumbing and
+    in-place row writes, and the kernel is still found by the benchmark's
+    pattern."""
+    from repro.models.blocks import ModelOpts
+    from repro.models.model import build_model
+    from repro.runtime.serve import BatchedServer
+    # the described chip is not the default backend: lower the kernel for
+    # it rather than for the interpreter
+    monkeypatch.setattr(kernel_ops, "_default_interpret", lambda: False)
+    B, S = SERVED[cell]
+    cfg = dataclasses.replace(REGISTRY["qwen1.5-4b"], n_layers=2)
+    model = build_model(cfg)
+    srv = BatchedServer(model, None, batch_size=B, max_seq=S,
+                        opts=ModelOpts(remat="none"), use_kernel=True)
+    on_chip = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,  # noqa: E731
+                                             sharding=one_chip)
+    hlo = srv._decode.lower(
+        jax.tree.map(on_chip, model.abstract_params(jnp.bfloat16)),
+        jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip),
+        jax.tree.map(on_chip, srv.cache)).compile().as_text()
+    slab = B * cfg.n_kv_heads * S * cfg.head_dim
+    big = _cache_sized(hlo, slab)
+    assert [x for x in big if x[1] not in IN_PLACE_OPS] == []
+    assert sum(op == "dynamic-update-slice" for _, op in big) == 2 * B
     kernel = re.compile(_bench_kernel_pattern())
     ops = [ln.strip().removeprefix("ROOT ") for ln in hlo.splitlines()]
     assert any(kernel.search(op) and "tpu_custom_call" in op for op in ops)

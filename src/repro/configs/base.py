@@ -34,13 +34,27 @@ class ArchConfig:
     sliding_window: int = 0          # >0 => local attention window
     local_global_ratio: int = 0      # e.g. 5 => pattern [local x5, global] (gemma3)
     qkv_bias: bool = False
+    attn_out_bias: bool = False      # bias on the output projection (PhiMoE)
     rope_theta: float = 10_000.0
+    # --- normalization (pre-norm blocks and the final norm) ---
+    norm: str = "rms"                # rms | layer (LayerNorm with bias)
+    norm_eps: float = 1e-6
+    # dtype of the activations: the residual stream, the norms and the
+    # input of every product; None is ``dtype``.  Wider than the weights
+    # (float32 against bf16), a product takes its input as two parts in the
+    # weights' dtype and keeps about float32 precision (``kernels.twopart``)
+    act_dtype: Optional[str] = None
     # --- MLP ---
     activation: str = "swiglu"       # swiglu | geglu | gelu
     # --- MoE ---
     n_experts: int = 0
     top_k: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25    # training path only (``moe.moe_ffn``)
+    router: str = "softmax"          # softmax | sparsemixer (PhiMoE)
+    router_eps: float = 0.0          # sparsemixer's jitter epsilon
+    # the experts of every MoE layer this chip holds, (first, count) out of
+    # ``n_experts``; None holds them all.  The router keeps all n_experts.
+    held_experts: Optional[Tuple[int, int]] = None
     # --- SSM (Mamba2 / SSD) ---
     ssm_state: int = 0
     ssm_head_dim: int = 64
@@ -56,6 +70,7 @@ class ArchConfig:
     frame_dim: int = 0
     # --- misc ---
     tie_embeddings: bool = False
+    lm_head_bias: bool = False
     dtype: str = "bfloat16"
 
     # ------------------------------------------------------------------
@@ -75,6 +90,15 @@ class ArchConfig:
     @property
     def kv_dim(self) -> int:
         return self.n_kv_heads * self.head_dim
+
+    @property
+    def activation_dtype(self) -> str:
+        return self.act_dtype or self.dtype
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        """(first, count) of the experts held here."""
+        return self.held_experts or (0, self.n_experts)
 
     @property
     def is_encoder_only(self) -> bool:
@@ -99,8 +123,11 @@ class ArchConfig:
     def n_params(self) -> int:
         """Analytic parameter count (used for 6ND model-FLOPs and sanity)."""
         d, f, v = self.d_model, self.d_ff, self.vocab
-        emb = v * d * (1 if self.tie_embeddings else 2)
+        emb = v * d * (1 if self.tie_embeddings else 2) + \
+            v * self.lm_head_bias
         per_layer_attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
+        per_layer_attn += d * self.attn_out_bias
+        norm = d * (2 if self.norm == "layer" else 1)     # LayerNorm's bias
         if self.activation in ("swiglu", "geglu"):
             mlp = 3 * d * f
         else:
@@ -108,9 +135,10 @@ class ArchConfig:
         total = emb
         for kind, _ in self.layer_pattern():
             if kind in (DENSE, ENCODER):
-                total += per_layer_attn + mlp + 2 * d
+                total += per_layer_attn + mlp + 2 * norm
             elif kind == MOE:
-                total += per_layer_attn + self.n_experts * mlp + d * self.n_experts + 2 * d
+                total += per_layer_attn + self.held[1] * mlp + \
+                    d * self.n_experts + 2 * norm
             elif kind == MAMBA:
                 di, st, h = self.d_inner, self.ssm_state, self.ssm_heads
                 conv_dim = di + 2 * st
@@ -125,13 +153,13 @@ class ArchConfig:
         return total
 
     def n_active_params(self) -> int:
-        """Active params per token (MoE: top_k of n_experts)."""
+        """Active params per token (MoE: top_k of the experts held)."""
         if not self.n_experts:
             return self.n_params()
         d, f = self.d_model, self.d_ff
         mlp = 3 * d * f if self.activation in ("swiglu", "geglu") else 2 * d * f
         inactive = sum(
-            (self.n_experts - self.top_k) * mlp
+            (self.held[1] - self.top_k) * mlp
             for kind, _ in self.layer_pattern() if kind == MOE
         )
         return self.n_params() - inactive
@@ -168,6 +196,7 @@ class ArchConfig:
             vocab=256,
             n_experts=min(self.n_experts, 4),
             top_k=min(self.top_k, 2),
+            held_experts=None,
             ssm_state=min(self.ssm_state, 16) if self.ssm_state else 0,
             ssm_head_dim=16,
             sliding_window=min(self.sliding_window, 8) if self.sliding_window else 0,

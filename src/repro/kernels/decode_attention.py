@@ -6,6 +6,10 @@ grid axis, with online-softmax accumulators ((G,D) f32 + (G,1) max/sum) in
 VMEM scratch, GQA folded as G query heads per KV head.  The per-sequence
 ``length`` vector rides in SMEM by scalar prefetch: a ``(1,)`` VMEM block of
 a ``(B,)`` array is only legal for the TPU lowering when B == 1.
+
+With ``precise`` both products take each float32 operand as two bf16 parts
+(``twopart.dot_general``): about float32 precision, where a plain float32
+product on the chip may round its operands to bf16.
 """
 from __future__ import annotations
 
@@ -17,11 +21,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import twopart
+
 NEG_INF = -1e30
 
 
+def _dot(a, b, dims, precise: bool):
+    if precise:
+        return twopart.dot_general(a, b, dims)
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
 def _kernel(len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
-            l_ref, *, scale: float, bk: int, n_kb: int):
+            l_ref, *, scale: float, bk: int, n_kb: int, precise: bool):
     b = pl.program_id(0)
     kb = pl.program_id(2)
 
@@ -36,8 +48,7 @@ def _kernel(len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
     v = v_ref[0, 0].astype(jnp.float32)             # (bk, D)
     length = len_ref[b]
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
+    s = _dot(q, k, (((1,), (1,)), ((), ())), precise) * scale
     kpos = kb * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(kpos < length, s, NEG_INF)
 
@@ -46,8 +57,8 @@ def _kernel(len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
     alpha = jnp.exp(m_prev - m_cur)
     p = jnp.exp(s - m_cur)
     l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    acc_ref[...] = acc_ref[...] * alpha + _dot(
+        p, v, (((1,), (0,)), ((), ())), precise)
     m_ref[...] = m_cur
 
     @pl.when(kb == n_kb - 1)
@@ -56,9 +67,9 @@ def _kernel(len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
                        jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("bk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bk", "precise", "interpret"))
 def decode_attention(q, k, v, length, layer=0, *, bk: int = 512,
-                     interpret: bool = False):
+                     precise: bool = False, interpret: bool = False):
     """q: (B,Hq,D) one token; k,v: (B,Hkv,S,D), or (L,B,Hkv,S,D) of which
     slab ``layer`` is read; attends positions < length.
 
@@ -97,7 +108,8 @@ def decode_attention(q, k, v, length, layer=0, *, bk: int = 512,
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, bk=bk, n_kb=n_kb),
+        functools.partial(_kernel, scale=scale, bk=bk, n_kb=n_kb,
+                          precise=precise),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
         compiler_params=pltpu.CompilerParams(

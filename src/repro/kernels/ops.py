@@ -9,6 +9,7 @@ from __future__ import annotations
 import jax
 
 from repro.kernels.decode_attention import decode_attention as _decode
+from repro.kernels.expert_ffn import expert_ffn as _experts
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.ssd_scan import ssd_scan as _ssd
 
@@ -41,7 +42,15 @@ def ssd(x, dt, A, Bm, Cm, D, *, chunk=128, interpret=None):
     return _ssd(x, dt, A, Bm, Cm, D, chunk=chunk, interpret=interpret)
 
 
-def decode_attention(q, k, v, length, layer=0, *, bk=512, interpret=None):
+def decode_attention(q, k, v, length, layer=0, *, bk=512, precise=False,
+                     interpret=None):
     if interpret is None:
         interpret = _default_interpret()
-    return _decode(q, k, v, length, layer, bk=bk, interpret=interpret)
+    return _decode(q, k, v, length, layer, bk=bk, precise=precise,
+                   interpret=interpret)
+
+
+def expert_ffn(x, gates, wg, wi, wo, layer=0, *, interpret=None):
+    if interpret is None:
+        interpret = _default_interpret()
+    return _experts(x, gates, wg, wi, wo, layer, interpret=interpret)

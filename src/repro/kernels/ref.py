@@ -49,3 +49,16 @@ def ssd_ref(x, dt, A, Bm, Cm, D, chunk: int):
     """Delegates to the model-layer chunked SSD reference (same math)."""
     from repro.models.ssm import ssd_reference
     return ssd_reference(x, dt, A, Bm, Cm, D, chunk)
+
+
+def expert_ffn_ref(x, gates, wg, wi, wo):
+    """x: (B,D); gates: (B,E); wg, wi: (E,D,F); wo: (E,F,D) -> (B,D) f32.
+    One expert at a time: each token's SwiGLU output through it, times the
+    token's gate for it."""
+    f32 = jnp.float32
+    out = jnp.zeros(x.shape, f32)
+    for e in range(wg.shape[0]):
+        h = jax.nn.silu(x.astype(f32) @ wg[e].astype(f32)) * \
+            (x.astype(f32) @ wi[e].astype(f32))
+        out = out + gates[:, e:e + 1] * (h @ wo[e].astype(f32))
+    return out

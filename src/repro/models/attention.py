@@ -15,7 +15,8 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.distrib.logical import P, ShardCtx
-from repro.models.layers import rope
+from repro.kernels import twopart
+from repro.models.layers import linear, rope
 
 NEG_INF = -1e30
 
@@ -35,12 +36,14 @@ def attn_spec(cfg: ArchConfig, cross: bool = False) -> dict:
         spec["bq"] = P((qd,), ("q_heads",), init="zeros")
         spec["bk"] = P((kd,), ("kv_heads",), init="zeros")
         spec["bv"] = P((kd,), ("kv_heads",), init="zeros")
+    if cfg.attn_out_bias and not cross:
+        spec["bo"] = P((d,), ("embed",), init="zeros")
     return spec
 
 
 def project_q(p, x, cfg: ArchConfig):
     dt = x.dtype
-    q = x @ p["wq"].astype(dt)
+    q = linear(x, p["wq"])
     if "bq" in p:
         q = q + p["bq"].astype(dt)
     B, S = x.shape[:2]
@@ -49,8 +52,8 @@ def project_q(p, x, cfg: ArchConfig):
 
 def project_kv(p, x, cfg: ArchConfig):
     dt = x.dtype
-    k = x @ p["wk"].astype(dt)
-    v = x @ p["wv"].astype(dt)
+    k = linear(x, p["wk"])
+    v = linear(x, p["wv"])
     if "bk" in p:
         k = k + p["bk"].astype(dt)
         v = v + p["bv"].astype(dt)
@@ -62,7 +65,10 @@ def project_kv(p, x, cfg: ArchConfig):
 
 def out_proj(p, o, cfg: ArchConfig):
     B, S = o.shape[:2]
-    return o.reshape(B, S, cfg.q_dim) @ p["wo"].astype(o.dtype)
+    out = linear(o.reshape(B, S, cfg.q_dim), p["wo"])
+    if "bo" in p:
+        out = out + p["bo"].astype(o.dtype)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -335,7 +341,8 @@ def decode_self_attention(
         v_cache = write_rows(v_cache, v_new, pos, at=(layer,))
         o = kernel_ops.decode_attention(
             q.astype(k_cache.dtype).reshape(B, cfg.n_heads, cfg.head_dim),
-            k_cache, v_cache, posv[:, 0].astype(jnp.int32) + 1, layer)
+            k_cache, v_cache, posv[:, 0].astype(jnp.int32) + 1, layer,
+            precise=twopart.wider(x.dtype, cfg.dtype))
         o = o.reshape(B, 1, cfg.n_heads, cfg.head_dim)
         return out_proj(p, o.astype(x.dtype), cfg), k_cache, v_cache
     o = decode_mha(q, k_cache, v_cache, ctx, pos=pos,

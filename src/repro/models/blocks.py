@@ -12,7 +12,8 @@ from repro.distrib.logical import ShardCtx
 from repro.models import attention as attn
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
-from repro.models.layers import mlp, mlp_spec, rmsnorm, rmsnorm_spec
+from repro.models.layers import (mlp, mlp_spec, norm, norm_spec, rmsnorm,
+                                 rmsnorm_spec)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -40,9 +41,9 @@ def remat_wrap(fn, opts: ModelOpts):
 # ---------------------------------------------------------------------------
 def dense_block_spec(cfg: ArchConfig) -> dict:
     spec = {
-        "ln1": rmsnorm_spec(cfg.d_model),
+        "ln1": norm_spec(cfg),
         "attn": attn.attn_spec(cfg),
-        "ln2": rmsnorm_spec(cfg.d_model),
+        "ln2": norm_spec(cfg),
     }
     if cfg.n_experts:
         spec["moe"] = moe_mod.moe_spec(cfg)
@@ -56,11 +57,11 @@ def dense_block(p, h, cfg: ArchConfig, ctx: ShardCtx, opts: ModelOpts, *,
     """Returns (h, aux_loss)."""
     h = ctx.constrain(h, "batch", "seq", "act_embed")
     a = attn.self_attention(
-        p["attn"], rmsnorm(p["ln1"], h), cfg, ctx,
+        p["attn"], norm(p["ln1"], h, cfg), cfg, ctx,
         positions=positions, is_global=is_global, chunk=opts.attn_chunk,
         banded=banded)
     h = h + a
-    hn = rmsnorm(p["ln2"], h)
+    hn = norm(p["ln2"], h, cfg)
     if cfg.n_experts:
         f = moe_mod.moe_ffn(p["moe"], hn, cfg, ctx)
         aux = moe_mod.router_aux_loss(p["moe"], hn, cfg)
@@ -72,25 +73,33 @@ def dense_block(p, h, cfg: ArchConfig, ctx: ShardCtx, opts: ModelOpts, *,
 
 def dense_block_decode(p, h, k_cache, v_cache, cfg: ArchConfig,
                        ctx: ShardCtx, *, pos, is_global=True,
-                       use_kernel: bool = False, layer=None):
-    """One-token step.  Returns (h, k, v).
+                       use_kernel: bool = False, layer=None, experts=None):
+    """One-token step.  Returns (h, k, v, held).
 
     ``pos`` may be scalar (lockstep) or ``(B,)`` per-slot positions.  On
     the reference path the caches are this layer's read-only slabs and
     ``k, v`` its new rows; with ``use_kernel`` they are the whole stacks,
     ``layer`` indexes them, and ``k, v`` are the stacks with this layer's
     rows written in place (``attention.decode_self_attention``).
+
+    An MoE layer is the dropless held-experts layer
+    (``moe.held_experts_ffn``); with ``use_kernel`` its expert weights are
+    ``experts``, the stacks of every layer, read at ``layer``.  ``held`` is
+    each token's held-expert bits, ``(B, 1)`` int32; None for a dense layer.
     """
     a, k_new, v_new = attn.decode_self_attention(
-        p["attn"], rmsnorm(p["ln1"], h), k_cache, v_cache, cfg, ctx,
+        p["attn"], norm(p["ln1"], h, cfg), k_cache, v_cache, cfg, ctx,
         pos=pos, is_global=is_global, use_kernel=use_kernel, layer=layer)
     h = h + a
-    hn = rmsnorm(p["ln2"], h)
+    hn = norm(p["ln2"], h, cfg)
+    held = None
     if cfg.n_experts:
-        f = moe_mod.moe_ffn(p["moe"], hn, cfg, ctx)
+        f, held = moe_mod.held_experts_ffn(
+            p["moe"], hn, cfg, experts=experts, layer=layer,
+            use_kernel=use_kernel)
     else:
         f = mlp(p["mlp"], hn, cfg, ctx)
-    return h + f, k_new, v_new
+    return h + f, k_new, v_new, held
 
 
 # ---------------------------------------------------------------------------
@@ -130,11 +139,12 @@ def mamba_block_spec(cfg: ArchConfig) -> dict:
 
 def mamba_block(p, h, cfg: ArchConfig, ctx: ShardCtx, opts: ModelOpts):
     h = ctx.constrain(h, "batch", "seq", "act_embed")
-    return h + ssm_mod.mamba_block(p["mixer"], rmsnorm(p["ln"], h), cfg, ctx,
-                                   use_kernel=opts.use_kernel)
+    return h + ssm_mod.mamba_block(p["mixer"],
+                                   rmsnorm(p["ln"], h, cfg.norm_eps), cfg,
+                                   ctx, use_kernel=opts.use_kernel)
 
 
 def mamba_block_decode(p, h, cache, cfg: ArchConfig, ctx: ShardCtx):
     y, cache = ssm_mod.mamba_decode_step(
-        p["mixer"], rmsnorm(p["ln"], h), cache, cfg, ctx)
+        p["mixer"], rmsnorm(p["ln"], h, cfg.norm_eps), cache, cfg, ctx)
     return h + y, cache

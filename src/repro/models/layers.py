@@ -8,10 +8,11 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.distrib.logical import P, ShardCtx
+from repro.kernels import twopart
 
 
 # ---------------------------------------------------------------------------
-# RMSNorm
+# RMSNorm and LayerNorm
 # ---------------------------------------------------------------------------
 def rmsnorm_spec(d: int) -> dict:
     return {"scale": P((d,), ("embed",), init="ones")}
@@ -23,6 +24,40 @@ def rmsnorm(params, x: jax.Array, eps: float = 1e-6) -> jax.Array:
     var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
     x = x * jax.lax.rsqrt(var + eps)
     return (x * params["scale"].astype(jnp.float32)).astype(dt)
+
+
+def layernorm(params, x: jax.Array, eps: float) -> jax.Array:
+    dt = x.dtype
+    x = x.astype(jnp.float32)
+    x = x - jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+    x = x * jax.lax.rsqrt(var + eps)
+    return (x * params["scale"].astype(jnp.float32)
+            + params["bias"].astype(jnp.float32)).astype(dt)
+
+
+def norm_spec(cfg: ArchConfig, d: Optional[int] = None) -> dict:
+    """The block norm's parameters: a scale, and a bias for LayerNorm."""
+    d = d or cfg.d_model
+    spec = rmsnorm_spec(d)
+    if cfg.norm == "layer":
+        spec["bias"] = P((d,), ("embed",), init="zeros")
+    return spec
+
+
+def norm(params, x: jax.Array, cfg: ArchConfig) -> jax.Array:
+    """The configuration's block norm (``cfg.norm``) at its epsilon."""
+    if cfg.norm == "layer":
+        return layernorm(params, x, cfg.norm_eps)
+    return rmsnorm(params, x, cfg.norm_eps)
+
+
+def linear(x: jax.Array, w: jax.Array) -> jax.Array:
+    """x @ w in x's dtype; float32 ``x`` against narrower weights keeps
+    float32 precision and reads ``w`` once (``twopart.matmul``)."""
+    if twopart.wider(x.dtype, w.dtype):
+        return twopart.matmul(x, w)
+    return x @ w.astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -80,6 +115,8 @@ def embed_spec(cfg: ArchConfig) -> dict:
     spec = {"tok": P((cfg.vocab, cfg.d_model), ("vocab", "embed"))}
     if not cfg.tie_embeddings:
         spec["unembed"] = P((cfg.d_model, cfg.vocab), ("embed", "vocab"))
+    if cfg.lm_head_bias:
+        spec["unembed_bias"] = P((cfg.vocab,), ("vocab",), init="zeros")
     return spec
 
 
@@ -95,8 +132,11 @@ def unembed_matrix(params, cfg: ArchConfig, dtype) -> jax.Array:
 
 def logits_last(params, cfg: ArchConfig, h_last: jax.Array) -> jax.Array:
     """(B, D) -> (B, V) logits for decode."""
-    w = unembed_matrix(params, cfg, h_last.dtype)
-    return (h_last @ w).astype(jnp.float32)
+    w = unembed_matrix(params, cfg, jnp.dtype(cfg.dtype))
+    logits = linear(h_last, w).astype(jnp.float32)
+    if cfg.lm_head_bias:
+        logits = logits + params["unembed_bias"].astype(jnp.float32)
+    return logits
 
 
 def chunked_cross_entropy(
@@ -114,6 +154,8 @@ def chunked_cross_entropy(
     assert S % chunk == 0, (S, chunk)
     n = S // chunk
     w = unembed_matrix(params, cfg, h.dtype)     # (D, V)
+    bias = params["unembed_bias"].astype(jnp.float32) if cfg.lm_head_bias \
+        else 0.0
 
     h_c = h.reshape(B, n, chunk, D).transpose(1, 0, 2, 3)
     y_c = labels.reshape(B, n, chunk).transpose(1, 0, 2)
@@ -121,7 +163,7 @@ def chunked_cross_entropy(
     def body(carry, xs):
         loss_sum, count = carry
         hc, yc = xs
-        logits = (hc @ w).astype(jnp.float32)            # (B, chunk, V)
+        logits = (hc @ w).astype(jnp.float32) + bias     # (B, chunk, V)
         logits = ctx.constrain(logits, "batch", "seq", "vocab")
         lse = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(

@@ -25,8 +25,8 @@ from repro.models import blocks as B
 from repro.models import ssm as ssm_mod
 from repro.models.blocks import ModelOpts
 from repro.models.layers import (
-    chunked_cross_entropy, embed, embed_spec, logits_last, rmsnorm,
-    rmsnorm_spec)
+    chunked_cross_entropy, embed, embed_spec, logits_last, norm, norm_spec,
+    rmsnorm)
 
 
 # ---------------------------------------------------------------------------
@@ -64,7 +64,7 @@ class Model:
     def param_spec(self) -> dict:
         cfg = self.cfg
         spec: Dict[str, Any] = {"embed": embed_spec(cfg),
-                                "ln_f": rmsnorm_spec(cfg.d_model)}
+                                "ln_f": norm_spec(cfg)}
         if cfg.family == "audio":
             spec["frame_proj"] = P((cfg.frame_dim, cfg.d_model),
                                    (None, "embed"))
@@ -109,7 +109,7 @@ class Model:
         cfg = self.cfg
         dtype = jnp.dtype(cfg.dtype)
         params = _precast(params, dtype, self.param_spec(), ctx)
-        h = self._embed_in(params, batch, dtype)
+        h = self._embed_in(params, batch, dtype).astype(cfg.activation_dtype)
         h = ctx.constrain(h, "batch", "seq", "act_embed")
         S = h.shape[1]
         positions = jnp.arange(S)[None]
@@ -180,7 +180,7 @@ class Model:
         else:
             raise ValueError(cfg.family)
 
-        return rmsnorm(params["ln_f"], h), aux
+        return norm(params["ln_f"], h, cfg), aux
 
     def _forward_banded(self, params, h, cfg, ctx, opts, positions):
         """Local:global superblock scan (e.g. gemma3's 5:1 pattern).
@@ -237,7 +237,7 @@ class Model:
         cfg = self.cfg
         dtype = jnp.dtype(cfg.dtype)
         params = _precast(params, dtype, self.param_spec(), ctx)
-        h = self._embed_in(params, batch, dtype)
+        h = self._embed_in(params, batch, dtype).astype(cfg.activation_dtype)
         h = ctx.constrain(h, "batch", "seq", "act_embed")
         S = h.shape[1]
         positions = jnp.arange(S)[None]
@@ -308,7 +308,7 @@ class Model:
         else:
             raise ValueError(cfg.family)
 
-        h = rmsnorm(params["ln_f"], h)
+        h = norm(params["ln_f"], h, cfg)
         return logits_last(params["embed"], cfg, h[:, -1]), cache
 
     # ---------------- decode ----------------
@@ -349,11 +349,14 @@ class Model:
         raise ValueError(f"{cfg.family} has no decode cache")
 
     def decode_step(self, params, batch, cache, ctx: ShardCtx = NOSHARD,
-                    opts: ModelOpts = ModelOpts()):
+                    opts: ModelOpts = ModelOpts(), with_stats: bool = False):
         """One token for every sequence in the batch.
 
         batch: {"token": (B,1) int32, "pos": scalar int32 or (B,) int32}
-        -> (logits (B,V) f32, new cache)
+        -> (logits (B,V) f32, new cache), and with ``with_stats`` a dict of
+        per-step counts: for an MoE model ``held``, ``(B, L)`` int32, bit
+        ``e`` set where the slot's token was routed to held expert ``e`` in
+        that layer (dense/moe families); empty otherwise.
 
         A scalar ``pos`` is the lockstep path (every sequence at the same
         position); a ``(B,)`` vector gives each slot its own position —
@@ -370,11 +373,18 @@ class Model:
         out of the stack; otherwise every layer reads its slab read-only and
         the rows are written after the scan.
 
+        An MoE layer is the dropless held-experts layer
+        (``moe.held_experts_ffn``).  On the kernel path the expert weights
+        stay out of the layer scan's sliced inputs: the ``expert_ffn``
+        kernel reads each layer's experts out of the whole stacks.
+
         Traced under the name scope ``decode_step``, so that its operations
         carry that name in a profile.
         """
         with jax.named_scope("decode_step"):
-            return self._decode_step(params, batch, cache, ctx, opts)
+            logits, cache, stats = self._decode_step(params, batch, cache,
+                                                     ctx, opts)
+        return (logits, cache, stats) if with_stats else (logits, cache)
 
     def _decode_step(self, params, batch, cache, ctx: ShardCtx,
                      opts: ModelOpts):
@@ -384,32 +394,41 @@ class Model:
         pos = batch["pos"]
         per_slot = jnp.ndim(pos) == 1
         h = embed(params["embed"], batch["token"], dtype)   # (B,1,D)
+        h = h.astype(cfg.activation_dtype)
         h = ctx.constrain(h, "batch", "seq", "act_embed")
 
+        stats: Dict[str, Any] = {}
         if cfg.family in ("dense", "moe") and opts.use_kernel:
+            layers, experts = params["layers"], None
+            if cfg.n_experts:
+                moe = layers["moe"]
+                experts = {k: moe[k] for k in ("wg", "wi", "wo")}
+                layers = dict(layers, moe={"router": moe["router"]})
+
             # the whole caches ride in the carry: each layer writes its rows
             # in place and the kernel reads its slab out of the stack
             def body(carry, xs):
                 hh, kc, vc = carry
                 p_i, flag, layer = xs
-                return B.dense_block_decode(
+                hh, kc, vc, held = B.dense_block_decode(
                     p_i, hh, kc, vc, cfg, ctx, pos=pos, is_global=flag,
-                    use_kernel=True, layer=layer), None
+                    use_kernel=True, layer=layer, experts=experts)
+                return (hh, kc, vc), held
 
-            (h, kc, vc), _ = jax.lax.scan(
+            (h, kc, vc), held = jax.lax.scan(
                 body, (h, cache["k"], cache["v"]),
-                (params["layers"], jnp.asarray(self.global_flags()),
+                (layers, jnp.asarray(self.global_flags()),
                  jnp.arange(cfg.n_layers, dtype=jnp.int32)))
             cache = {"k": kc, "v": vc}
 
         elif cfg.family in ("dense", "moe"):
             def body(hh, xs):
                 p_i, flag, kc, vc = xs
-                hh, kn, vn = B.dense_block_decode(
+                hh, kn, vn, held = B.dense_block_decode(
                     p_i, hh, kc, vc, cfg, ctx, pos=pos, is_global=flag)
-                return hh, (kn, vn)
+                return hh, (kn, vn, held)
 
-            h, (kns, vns) = jax.lax.scan(
+            h, (kns, vns, held) = jax.lax.scan(
                 body, h, (params["layers"], jnp.asarray(self.global_flags()),
                           cache["k"], cache["v"]))
             # every layer's rows written after the scan, in place
@@ -442,7 +461,7 @@ class Model:
             def group(hh, xs):
                 p_g, cg, kc, vc = xs
                 hh, cg = jax.lax.scan(inner, hh, (p_g, cg))
-                hh, kn, vn = B.dense_block_decode(
+                hh, kn, vn, _ = B.dense_block_decode(
                     shared, hh, kc, vc, cfg, ctx, pos=pos)
                 return hh, (cg, kn, vn)
 
@@ -472,7 +491,7 @@ class Model:
 
             def inner(hh, xs):
                 p_i, kc, vc = xs
-                hh, kn, vn = B.dense_block_decode(
+                hh, kn, vn, _ = B.dense_block_decode(
                     p_i, hh, kc, vc, cfg, ctx, pos=pos)
                 return hh, (kn, vn)
 
@@ -493,15 +512,17 @@ class Model:
         else:
             raise ValueError(f"{cfg.family} has no decode step")
 
-        h = rmsnorm(params["ln_f"], h)
-        return logits_last(params["embed"], cfg, h[:, 0]), cache
+        if cfg.family == "moe":
+            stats["held"] = held[..., 0].T                    # (B, L)
+        h = norm(params["ln_f"], h, cfg)
+        return logits_last(params["embed"], cfg, h[:, 0]), cache, stats
 
 
 # ---------------------------------------------------------------------------
 # Prefill block variants (return the projected K/V so the cache can be built)
 # ---------------------------------------------------------------------------
 def _dense_prefill(p, h, cfg, ctx, opts, positions, is_global):
-    hn = rmsnorm(p["ln1"], h)
+    hn = norm(p["ln1"], h, cfg)
     q = attn_mod.project_q(p["attn"], hn, cfg)
     k, v = attn_mod.project_kv(p["attn"], hn, cfg)
     q = attn_mod.rope(q, positions, cfg.rope_theta)
@@ -510,10 +531,10 @@ def _dense_prefill(p, h, cfg, ctx, opts, positions, is_global):
         q, k, v, ctx, causal=cfg.causal, is_global=is_global,
         window=cfg.sliding_window, chunk=opts.attn_chunk)
     h = h + attn_mod.out_proj(p["attn"], o, cfg)
-    hn = rmsnorm(p["ln2"], h)
-    if cfg.n_experts:
+    hn = norm(p["ln2"], h, cfg)
+    if cfg.n_experts:            # dropless, as the decode step serves it
         from repro.models import moe as moe_mod
-        f = moe_mod.moe_ffn(p["moe"], hn, cfg, ctx)
+        f, _ = moe_mod.held_experts_ffn(p["moe"], hn, cfg)
     else:
         from repro.models.layers import mlp
         f = mlp(p["mlp"], hn, cfg, ctx)
@@ -526,7 +547,7 @@ def _mamba_prefill(p, h, cfg, ctx, opts):
     dt_ = h.dtype
     B_, L, _ = h.shape
     di, n = cfg.d_inner, cfg.ssm_state
-    hn = rmsnorm(p["ln"], h)
+    hn = rmsnorm(p["ln"], h, cfg.norm_eps)
     zxbcdt = hn @ p["mixer"]["in_proj"].astype(dt_)
     z, xBC, dt = ssm_mod._split_proj(cfg, zxbcdt)
     xBC_conv = ctx.constrain(
@@ -542,7 +563,7 @@ def _mamba_prefill(p, h, cfg, ctx, opts):
     y, state = ssm_mod.ssd_reference(xs, dtv, A, Bm, Cm, p["mixer"]["D"],
                                      chunk=cfg.ssm_chunk, ctx=ctx)
     y = y.reshape(B_, L, di)
-    y = rmsnorm(p["mixer"]["norm"], y * jax.nn.silu(z))
+    y = rmsnorm(p["mixer"]["norm"], y * jax.nn.silu(z), cfg.norm_eps)
     h = h + y @ p["mixer"]["out_proj"].astype(dt_)
     conv_tail = xBC[:, L - (cfg.ssm_conv_width - 1):, :]   # pre-activation
     return h, (state, conv_tail.astype(dt_))

@@ -155,7 +155,7 @@ def mamba_block(p, x: jax.Array, cfg: ArchConfig, ctx: ShardCtx,
         y, _ = ssd_reference(xs, dt, A, Bm, Cm, p["D"], chunk=cfg.ssm_chunk,
                              ctx=ctx)
     y = y.reshape(B_, L, di)
-    y = rmsnorm(p["norm"], y * jax.nn.silu(z))
+    y = rmsnorm(p["norm"], y * jax.nn.silu(z), cfg.norm_eps)
     y = ctx.constrain(y, "batch", "seq", "act_ffn")
     return y @ p["out_proj"].astype(dt_)
 
@@ -203,6 +203,7 @@ def mamba_decode_step(p, x: jax.Array, cache: dict, cfg: ArchConfig,
     y = jnp.einsum("bhpn,bn->bhp", state, Cm) \
         + xs * p["D"].astype(jnp.float32)[None, :, None]
     y = y.reshape(B_, di)
-    y = rmsnorm(p["norm"], (y * jax.nn.silu(z.astype(jnp.float32))))
+    y = rmsnorm(p["norm"], (y * jax.nn.silu(z.astype(jnp.float32))),
+                cfg.norm_eps)
     y = (y.astype(dt_) @ p["out_proj"].astype(dt_))[:, None]
     return y, {"ssm": state, "conv": new_conv}

@@ -151,14 +151,20 @@ class BatchedServer:
     over those steps: the batch's useful width) and ``prompt_tokens`` (the
     slot-steps whose input token came from a prompt rather than from the
     slot's last output).  ``prompt_tokens / slot_steps`` is the share of
-    the decode steps' work spent feeding prompts one token at a time.
+    the decode steps' work spent feeding prompts one token at a time.  An
+    MoE model also counts, over the occupied slots' tokens and every layer,
+    ``routed_rows`` ((token, expert) pairs routed), ``held_rows`` (those
+    that landed on an expert held here) and ``expert_loads`` (the (layer,
+    held expert) pairs that took at least one row); the decode step
+    returns each slot's held-expert bits, pulled with the argmax.
 
     Every ``step()`` that runs a decode step is traced with
     ``jax.profiler.TraceAnnotation`` spans, which cost under a microsecond
     each on a TPU v5e host when no profiler is running: ``serve.step``
     around the call, with the stats ``step`` (``steps`` at entry, the
     index ``Request.started`` and ``.finished`` hold), ``slot_steps`` and
-    ``prompt_tokens`` as they stand at entry; inside it, in order,
+    ``prompt_tokens`` as they stand at entry, and for an MoE model
+    ``routed_rows``, ``held_rows`` and ``expert_loads``; inside it, in order,
     ``serve.admit`` (``serve.reset`` around an ssm slot's state reset),
     ``serve.dispatch`` (the host-to-device copies, the decode step and the
     argmax, all dispatched asynchronously), ``serve.sync`` (the host
@@ -201,6 +207,10 @@ class BatchedServer:
         self.steps = 0                     # completed decode steps
         self.slot_steps = 0                # occupied slots, summed over steps
         self.prompt_tokens = 0             # slot-steps fed a prompt token
+        self.routes = cfg.family == "moe"
+        self.routed_rows = 0               # (token, expert) pairs routed
+        self.held_rows = 0                 # ... that landed on held experts
+        self.expert_loads = 0              # (layer, held expert) pairs used
         self.queue: List[Request] = []
         self.active: List[Optional[Request]] = [None] * self.B
         self.results: Dict[int, List[int]] = {}
@@ -211,7 +221,7 @@ class BatchedServer:
 
         def decode_step(p, t, pos, c):
             return model.decode_step(p, {"token": t, "pos": pos}, c,
-                                     NOSHARD, dopts)
+                                     NOSHARD, dopts, with_stats=self.routes)
 
         self._decode = jax.jit(decode_step, donate_argnums=3)
 
@@ -240,24 +250,35 @@ class BatchedServer:
                 "use run()")
         if not self.queue and not any(a is not None for a in self.active):
             return []
-        with TraceAnnotation("serve.step", step=self.steps,
-                             slot_steps=self.slot_steps,
-                             prompt_tokens=self.prompt_tokens):
+        stats = dict(step=self.steps, slot_steps=self.slot_steps,
+                     prompt_tokens=self.prompt_tokens)
+        if self.routes:
+            stats.update(routed_rows=self.routed_rows,
+                         held_rows=self.held_rows,
+                         expert_loads=self.expert_loads)
+        with TraceAnnotation("serve.step", **stats):
             with TraceAnnotation("serve.admit"):
                 self._admit()
+            occupied = []
             for i, r in enumerate(self.active):
                 if r is not None:
-                    self.slot_steps += 1
+                    occupied.append(i)
                     self.prompt_tokens += int(self._cursor[i] < len(r.prompt))
+            self.slot_steps += len(occupied)
             with TraceAnnotation("serve.dispatch"):
-                logits, self.cache = self._decode(
+                logits, self.cache, *counts = self._decode(
                     self.params, jnp.asarray(self._token),
                     jnp.asarray(self._pos, jnp.int32), self.cache)
                 nxt = jnp.argmax(logits, -1)
             with TraceAnnotation("serve.sync"):
-                nxt = np.asarray(nxt)
+                if self.routes:
+                    nxt, held = jax.device_get((nxt, counts[0]["held"]))
+                else:
+                    nxt = np.asarray(nxt)
             self.steps += 1
             with TraceAnnotation("serve.walk"):
+                if self.routes:
+                    self._count_routes(held[occupied])
                 return self._walk(nxt)
 
     def drain(self) -> Dict[int, List[int]]:
@@ -293,6 +314,14 @@ class BatchedServer:
                 self._token[i, 0] = r.prompt[0]
                 r.started = self.steps
                 self._reset_slot(i)
+
+    def _count_routes(self, held: np.ndarray) -> None:
+        """Add one step's routes: ``held``, the occupied slots' (n, L)
+        held-expert bits."""
+        self.routed_rows += held.size * self.model.cfg.top_k
+        self.held_rows += int(np.bitwise_count(held).sum())
+        self.expert_loads += int(np.bitwise_count(
+            np.bitwise_or.reduce(held, axis=0)).sum())
 
     def _reset_slot(self, i: int) -> None:
         if self.model.cfg.family != "ssm":

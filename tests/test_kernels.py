@@ -7,8 +7,9 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels.decode_attention import decode_attention
+from repro.kernels.expert_ffn import expert_ffn
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.ref import decode_mha_ref, mha_ref, ssd_ref
+from repro.kernels.ref import decode_mha_ref, expert_ffn_ref, mha_ref, ssd_ref
 from repro.kernels.ssd_scan import ssd_scan
 
 TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
@@ -92,6 +93,27 @@ def test_decode_attention_sweep(B, Hq, Hkv, S, D, length, dt):
                                atol=TOL[dt], rtol=TOL[dt])
 
 
+def test_decode_attention_precise_matches_float32():
+    """``precise``: float32 q, K and V as two bf16 parts each, three exact
+    products: the oracle's float32 result to float32's tolerance, where
+    bf16 operands miss it by about 1e-3."""
+    B, Hq, Hkv, S, D = 2, 8, 2, 1024, 128
+    ks = jax.random.split(jax.random.PRNGKey(6), 3)
+    q = jax.random.normal(ks[0], (B, Hq, D))
+    k = jax.random.normal(ks[1], (B, Hkv, S, D))
+    v = jax.random.normal(ks[2], (B, Hkv, S, D))
+    length = jnp.array([1000, 17], jnp.int32)
+    want = np.asarray(decode_mha_ref(q, k, v, length=length[:, None, None]))
+    got = decode_attention(q, k, v, length, bk=512, precise=True,
+                           interpret=True)
+    np.testing.assert_allclose(np.asarray(got), want, atol=TOL[jnp.float32],
+                               rtol=TOL[jnp.float32])
+    rounded = decode_attention(*(x.astype(jnp.bfloat16).astype(jnp.float32)
+                                 for x in (q, k, v)), length, bk=512,
+                               interpret=True)
+    assert np.abs(np.asarray(rounded) - want).max() > 1e-3
+
+
 @pytest.mark.parametrize("Hq,Hkv", [(20, 20), (32, 8)])    # MHA, GQA
 def test_decode_attention_reads_layer_of_stack(Hq, Hkv):
     """The kernel reads layer ``l`` of a stacked ``(L,B,Hkv,S,D)`` cache
@@ -114,6 +136,69 @@ def test_decode_attention_reads_layer_of_stack(Hq, Hkv):
         slab = decode_attention(q, k[layer], v[layer], length, bk=512,
                                 interpret=True)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(slab))
+
+
+def _routing(kind, B, E, key):
+    """(B, E) gates: every token on two experts at random weights
+    (uniform), all on expert 0 (one), or on none of the held ones
+    (absent)."""
+    if kind == "absent":
+        return jnp.zeros((B, E), jnp.float32)
+    if kind == "one":
+        return jnp.zeros((B, E), jnp.float32).at[:, 0].set(1.0)
+    pick = jax.random.permutation(key, jnp.tile(jnp.arange(E), (B, 1)),
+                                  axis=1, independent=True)[:, :2]
+    w = jax.random.uniform(key, (B, 2), minval=0.2, maxval=1.0)
+    return jnp.zeros((B, E)).at[jnp.arange(B)[:, None], pick].set(w)
+
+
+def _pallas_grid(fn, *args):
+    """(grid, block shapes) of the one pallas_call ``fn`` traces."""
+    def find(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                gm = eqn.params["grid_mapping"]
+                return gm.grid, [tuple(bm.block_shape)
+                                 for bm in gm.block_mappings]
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                got = find(sub)
+                if got:
+                    return got
+        return None
+    return find(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
+@pytest.mark.parametrize("dt,split", [(jnp.float32, False),
+                                      (jnp.bfloat16, False),
+                                      (jnp.bfloat16, True)])
+def test_expert_ffn_fixed_work_under_any_routing(dt, split):
+    """The held-experts kernel against a plain per-expert loop under
+    uniform, all-to-one-expert and all-to-absent routing, reading layer 1
+    of a stack; its grid and block shapes are the same in all three.  With
+    ``split``, float32 tokens against bf16 weights come out at float32's
+    tolerance."""
+    L, B, E, D, F = 2, 16, 4, 128, 384
+    ks = jax.random.split(jax.random.PRNGKey(5), 5)
+    x = jax.random.normal(ks[0], (B, D), jnp.float32 if split else dt)
+    wg = (jax.random.normal(ks[1], (L, E, D, F)) / D ** 0.5).astype(dt)
+    wi = (jax.random.normal(ks[2], (L, E, D, F)) / D ** 0.5).astype(dt)
+    wo = (jax.random.normal(ks[3], (L, E, F, D)) / F ** 0.5).astype(dt)
+    shapes = set()
+    for kind in ("uniform", "one", "absent"):
+        g = _routing(kind, B, E, ks[4])
+        run = lambda *a: expert_ffn(*a, 1, tf=128,  # noqa: E731
+                                    interpret=True)
+        out = run(x, g, wg, wi, wo)
+        want = expert_ffn_ref(x, g, wg[1], wi[1], wo[1])
+        # unsplit bf16: the SwiGLU output is rounded to bf16 before wo
+        t = TOL[jnp.float32 if split else dt]
+        tol = t * float(jnp.abs(want).max() + 1.0)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                                   atol=tol, rtol=t)
+        if kind == "absent":
+            assert not np.asarray(out).any()
+        shapes.add(repr(_pallas_grid(run, x, g, wg, wi, wo)))
+    assert len(shapes) == 1 and "(4, 3)" in shapes.pop()
 
 
 @settings(max_examples=10, deadline=None)

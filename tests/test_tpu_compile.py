@@ -51,13 +51,12 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _bench_kernel_pattern() -> str:
-    """``KERNEL``, the pattern by which the benchmark finds the decode-
-    attention kernel among a trace's ops (read from its source, which
-    imports modules only the benchmark's path holds)."""
+def _bench_kernel_pattern(reader: str = "decode_attention_roofline") -> str:
+    """``KERNEL``, the pattern by which the benchmark's ``reader`` finds its
+    kernel among a trace's ops (read from its source, which imports modules
+    only the benchmark's path holds)."""
     path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "bench", "metrics",
-        "decode_attention_roofline.py")
+        os.path.abspath(__file__))), "bench", "metrics", f"{reader}.py")
     with open(path) as f:
         tree = ast.parse(f.read())
     for node in tree.body:
@@ -156,6 +155,67 @@ def test_served_decode_step_reads_and_writes_cache_in_place(
     kernel = re.compile(_bench_kernel_pattern())
     ops = [ln.strip().removeprefix("ROOT ") for ln in hlo.splitlines()]
     assert any(kernel.search(op) and "tpu_custom_call" in op for op in ops)
+
+
+def _served_step_text(model, B, S, one_chip) -> str:
+    """The compiled text of ``BatchedServer``'s jitted decode step for
+    ``model``, kernels on, per-slot positions, float32 cache."""
+    from repro.models.blocks import ModelOpts
+    from repro.runtime.serve import BatchedServer
+    srv = BatchedServer(model, None, batch_size=B, max_seq=S,
+                        opts=ModelOpts(remat="none"), use_kernel=True)
+    on_chip = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,  # noqa: E731
+                                             sharding=one_chip)
+    return srv._decode.lower(
+        jax.tree.map(on_chip, model.abstract_params(jnp.bfloat16)),
+        jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip),
+        jax.tree.map(on_chip, srv.cache)).compile().as_text()
+
+
+def _instructions(hlo: str):
+    return [m for m in map(_INSTR.match, hlo.splitlines()) if m]
+
+
+# instruction lines of the served qwen1.5-4b step at 2 layers, as compiled
+# before the MoE layer and LayerNorm came in: shared code left it unchanged
+QWEN_INSTRUCTIONS = {"chat": 699, "summarize": 632}
+
+
+@pytest.mark.parametrize("cell", sorted(SERVED))
+def test_served_qwen_decode_step_unchanged(one_chip, monkeypatch, cell):
+    from repro.models.model import build_model
+    monkeypatch.setattr(kernel_ops, "_default_interpret", lambda: False)
+    B, S = SERVED[cell]
+    model = build_model(dataclasses.replace(REGISTRY["qwen1.5-4b"],
+                                            n_layers=2))
+    hlo = _served_step_text(model, B, S, one_chip)
+    assert len(_instructions(hlo)) == QWEN_INSTRUCTIONS[cell]
+
+
+def test_served_phi_moe_decode_step_compiles(one_chip, monkeypatch):
+    """``BatchedServer``'s decode step at the Phi-3.5-MoE cell's shapes
+    (B=64, max_seq 512, published widths, 8 of 16 experts held; depth cut
+    to 2 layers): both kernels are found by the benchmark's patterns, and
+    no instruction holds as much as one layer's held experts except as a
+    parameter or loop plumbing: the expert kernel reads them in place."""
+    from repro.models.model import build_model
+    monkeypatch.setattr(kernel_ops, "_default_interpret", lambda: False)
+    cfg = dataclasses.replace(REGISTRY["phi3.5-moe-42b-a6.6b"], n_layers=2,
+                              held_experts=(0, 8))
+    hlo = _served_step_text(build_model(cfg), 64, 512, one_chip)
+    ops = [ln.strip().removeprefix("ROOT ") for ln in hlo.splitlines()]
+    for reader in ("expert_ffn_roofline", "decode_attention_roofline"):
+        kernel = re.compile(_bench_kernel_pattern(reader))
+        assert any(kernel.search(op) and "tpu_custom_call" in op
+                   for op in ops), reader
+    layer_experts = 8 * 3 * cfg.d_model * cfg.d_ff
+    big = [(m.group(1), m.group(3)) for m in _instructions(hlo)
+           if any(math.prod(int(d) for d in dims.split(",") if d)
+                  >= layer_experts // 3
+                  for dims in re.findall(r"bf16\[([0-9,]*)\]",
+                                         m.group(2)))]
+    assert big and [x for x in big if x[1] not in IN_PLACE_OPS] == []
 
 
 def test_flash_attention_compiles(one_chip):

@@ -188,15 +188,17 @@ def _ref_us(provider: str, preset: str, reps: int) -> float:
 
 
 def grid_steps(provider: str, preset: str, config: Dict[str, Any]) -> int:
-    """Number of pallas grid steps one candidate launches — the
+    """Number of pallas grid steps one candidate launches (for
+    ``decode_attention``, the blocks its one invocation walks) — the
     quantity interpret-mode wall time is proportional to."""
     shape = PRESETS[preset][provider]
     if provider == "flash_attention":
         B, Hq, _Hkv, S, _D = shape
         return B * Hq * (S // int(config["bq"])) * (S // int(config["bk"]))
     if provider == "decode_attention":
-        B, Hq, _Hkv, S, _D, _length = shape
-        return B * Hq * (S // int(config["bk"]))
+        # one invocation walks each sequence's live blocks
+        B, _Hq, _Hkv, _S, _D, length = shape
+        return B * -(-length // int(config["bk"]))
     if provider == "ssd_scan":
         B, L, H, _P, _N = shape
         return B * H * (L // int(config["chunk"]))

@@ -42,7 +42,7 @@ def ssd(x, dt, A, Bm, Cm, D, *, chunk=128, interpret=None):
     return _ssd(x, dt, A, Bm, Cm, D, chunk=chunk, interpret=interpret)
 
 
-def decode_attention(q, k, v, length, layer=0, *, bk=512, precise=False,
+def decode_attention(q, k, v, length, layer=0, *, bk=None, precise=False,
                      interpret=None):
     if interpret is None:
         interpret = _default_interpret()

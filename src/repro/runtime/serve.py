@@ -24,6 +24,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.distrib.logical import NOSHARD
+from repro.kernels.decode_attention import block_size, live_blocks
 from repro.models.blocks import ModelOpts
 from repro.models.model import Model
 
@@ -156,15 +157,21 @@ class BatchedServer:
     ``routed_rows`` ((token, expert) pairs routed), ``held_rows`` (those
     that landed on an expert held here) and ``expert_loads`` (the (layer,
     held expert) pairs that took at least one row); the decode step
-    returns each slot's held-expert bits, pulled with the argmax.
+    returns each slot's held-expert bits, pulled with the argmax.  With
+    the kernel on, ``kv_blocks`` counts the KV blocks ``decode_attention``
+    reads in each layer, ``ceil((pos + 1) / bk)`` for every slot at the
+    position dispatched (a free slot sits at position 0: one block), and
+    ``kv_blocks_full`` the ``B * S / bk`` a kernel reading every position
+    would; both are computed on the host from the positions.
 
     Every ``step()`` that runs a decode step is traced with
     ``jax.profiler.TraceAnnotation`` spans, which cost under a microsecond
     each on a TPU v5e host when no profiler is running: ``serve.step``
     around the call, with the stats ``step`` (``steps`` at entry, the
     index ``Request.started`` and ``.finished`` hold), ``slot_steps`` and
-    ``prompt_tokens`` as they stand at entry, and for an MoE model
-    ``routed_rows``, ``held_rows`` and ``expert_loads``; inside it, in order,
+    ``prompt_tokens`` as they stand at entry, for an MoE model
+    ``routed_rows``, ``held_rows`` and ``expert_loads``, and with the
+    kernel on ``kv_blocks`` and ``kv_blocks_full``; inside it, in order,
     ``serve.admit`` (``serve.reset`` around an ssm slot's state reset),
     ``serve.dispatch`` (the host-to-device copies, the decode step and the
     argmax, all dispatched asynchronously), ``serve.sync`` (the host
@@ -211,6 +218,12 @@ class BatchedServer:
         self.routed_rows = 0               # (token, expert) pairs routed
         self.held_rows = 0                 # ... that landed on held experts
         self.expert_loads = 0              # (layer, held expert) pairs used
+        self.kv_blocks = 0                 # KV blocks the kernel reads
+        self.kv_blocks_full = 0            # ... if it read every position
+        if self.use_kernel:
+            k = self.cache["k"]            # (L, B, Hkv, S, D)
+            self._bk = block_size(max_seq, k.shape[2], k.shape[4],
+                                  k.dtype.itemsize)
         self.queue: List[Request] = []
         self.active: List[Optional[Request]] = [None] * self.B
         self.results: Dict[int, List[int]] = {}
@@ -256,6 +269,9 @@ class BatchedServer:
             stats.update(routed_rows=self.routed_rows,
                          held_rows=self.held_rows,
                          expert_loads=self.expert_loads)
+        if self.use_kernel:
+            stats.update(kv_blocks=self.kv_blocks,
+                         kv_blocks_full=self.kv_blocks_full)
         with TraceAnnotation("serve.step", **stats):
             with TraceAnnotation("serve.admit"):
                 self._admit()
@@ -265,6 +281,10 @@ class BatchedServer:
                     occupied.append(i)
                     self.prompt_tokens += int(self._cursor[i] < len(r.prompt))
             self.slot_steps += len(occupied)
+            if self.use_kernel:
+                self.kv_blocks += int(live_blocks(self._pos + 1,
+                                                  self._bk).sum())
+                self.kv_blocks_full += self.B * (self.S // self._bk)
             with TraceAnnotation("serve.dispatch"):
                 logits, self.cache, *counts = self._decode(
                     self.params, jnp.asarray(self._token),
@@ -365,4 +385,5 @@ class BatchedServer:
         r.finished = self.steps
         self.results[r.rid] = list(r.output)
         self.active[i] = None
+        self._pos[i] = 0       # a free slot attends to one position only
         finished.append(r)

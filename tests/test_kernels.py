@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels.decode_attention import decode_attention
+from repro.kernels.decode_attention import block_size, decode_attention
 from repro.kernels.expert_ffn import expert_ffn
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ref import decode_mha_ref, expert_ffn_ref, mha_ref, ssd_ref
@@ -136,6 +136,54 @@ def test_decode_attention_reads_layer_of_stack(Hq, Hkv):
         slab = decode_attention(q, k[layer], v[layer], length, bk=512,
                                 interpret=True)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(slab))
+
+
+def _ragged(Hq, Hkv, bk, key=5, S=512, D=128):
+    """q, K, V of five slots, the block size (``block_size``'s if ``bk`` is
+    None) and per-slot lengths 1, bk-1, bk, bk+1 and S."""
+    ks = jax.random.split(jax.random.PRNGKey(key), 3)
+    q = jax.random.normal(ks[0], (5, Hq, D))
+    k = jax.random.normal(ks[1], (5, Hkv, S, D))
+    v = jax.random.normal(ks[2], (5, Hkv, S, D))
+    bk = bk or block_size(S, Hkv, D, 4)
+    assert bk < S
+    return q, k, v, bk, jnp.array([1, bk - 1, bk, bk + 1, S], jnp.int32)
+
+
+@pytest.mark.parametrize("precise", [False, True])
+@pytest.mark.parametrize("bk", [None, 16])          # derived, explicit
+@pytest.mark.parametrize("Hq,Hkv", [(20, 20), (32, 8)])    # MHA, GQA
+def test_decode_attention_ragged_lengths(Hq, Hkv, bk, precise):
+    """Per-slot lengths on and around block boundaries, at the block size
+    derived from the shapes and at a small explicit one: the oracle's
+    result over each slot's own positions.  ``precise`` keeps about 16
+    significant bits of each operand (two bf16 parts), so it is held to
+    2**-14."""
+    q, k, v, bk, length = _ragged(Hq, Hkv, bk)
+    out = decode_attention(q, k, v, length, bk=bk, precise=precise,
+                           interpret=True)
+    ref = decode_mha_ref(q, k, v, length=length[:, None, None])
+    tol = 2.0 ** -14 if precise else TOL[jnp.float32]
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("bk", [None, 16])
+@pytest.mark.parametrize("Hq,Hkv", [(20, 20), (32, 8)])
+def test_decode_attention_reads_no_block_past_length(Hq, Hkv, bk):
+    """K and V are NaN from the first block boundary at or after each
+    slot's length: the output is the clean one to the bit, so no block
+    wholly past a slot's length is read (a masked score still multiplies
+    V, and 0 * NaN is NaN)."""
+    q, k, v, bk, length = _ragged(Hq, Hkv, bk)
+    S = k.shape[2]
+    past = jnp.arange(S)[None] >= (-(-length // bk) * bk)[:, None]
+    poison = lambda x: jnp.where(past[:, None, :, None], jnp.nan, x)  # noqa: E731
+    clean = decode_attention(q, k, v, length, bk=bk, interpret=True)
+    out = decode_attention(q, poison(k), poison(v), length, bk=bk,
+                           interpret=True)
+    assert np.asarray(past).any(axis=1).tolist() == [True] * 4 + [False]
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(clean))
 
 
 def _routing(kind, B, E, key):

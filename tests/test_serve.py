@@ -322,6 +322,73 @@ def test_work_counters_match_request_lengths(dense):
     assert srv.slot_steps < 2 * srv.steps               # slots sat empty
 
 
+def test_finished_slot_returns_to_position_zero(dense):
+    """A slot frees at position 0, so the decode kernel reads one block for
+    it rather than its last occupant's length; the neighbour decoding
+    beside it is unaffected."""
+    model, params = dense
+    srv = BatchedServer(model, params, batch_size=2, max_seq=64, opts=OPTS)
+    a = Request(rid=0, prompt=[2, 3], max_new_tokens=2)
+    b = Request(rid=1, prompt=[4, 5, 6], max_new_tokens=6)
+    srv.submit(a), srv.submit(b)
+    while not a.done:
+        srv.step()
+    assert srv.active[0] is None and srv._pos.tolist() == [0, 3]
+    out = srv.drain()
+    assert srv._pos.tolist() == [0, 0]
+    solo = BatchedServer(model, params, batch_size=2, max_seq=64, opts=OPTS)
+    assert out[1] == solo.run([Request(rid=1, prompt=[4, 5, 6],
+                                       max_new_tokens=6)])[1]
+
+
+def test_kv_block_counters_follow_dispatched_positions(dense, monkeypatch,
+                                                       tmp_path):
+    """With the kernel on, ``kv_blocks`` grows each step by ceil((pos + 1)
+    / bk) over every slot at the positions dispatched, and
+    ``kv_blocks_full`` by B * S / bk, with bk the kernel's own for the
+    cache; each ``serve.step`` carries both as they stood at entry.  At
+    16-position blocks the greedy tokens are the reference path's."""
+    from repro.kernels import decode_attention as kernel
+    monkeypatch.setattr(kernel, "MIN_BLOCK_BYTES", 1)    # bk 16 here
+    model, params = dense
+    B, S = 3, 48
+    mk = lambda: [Request(rid=0, prompt=[5, 6, 7], max_new_tokens=60),  # noqa: E731
+                  Request(rid=1, prompt=[9, 10], max_new_tokens=20),
+                  Request(rid=2, prompt=[1, 2, 3, 4], max_new_tokens=3),
+                  Request(rid=3, prompt=[11], max_new_tokens=30)]
+    srv = BatchedServer(model, params, batch_size=B, max_seq=S, opts=OPTS,
+                        use_kernel=True)
+    assert srv._bk == 16
+    dispatched, decode = [], srv._decode
+
+    def spy(p, t, pos, c):
+        # a copy: on the CPU ``pos`` may share the server's own array
+        dispatched.append(np.array(pos))
+        return decode(p, t, pos, c)
+
+    srv._decode = spy
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    out = srv.run(mk())
+    jax.profiler.stop_trace()
+
+    blocks = [int(np.ceil((pos + 1) / 16).sum()) for pos in dispatched]
+    assert max(blocks) > B                  # a slot beyond its first block
+    assert srv.kv_blocks == sum(blocks)
+    assert srv.kv_blocks_full == len(dispatched) * B * (S // 16)
+    steps = [s[3] for s in _serve_spans(str(tmp_path))
+             if s[0] == "serve.step"]
+    assert [s["kv_blocks"] for s in steps] == np.cumsum([0] + blocks[:-1]
+                                                        ).tolist()
+    assert [s["kv_blocks_full"] for s in steps] == \
+        [i * B * (S // 16) for i in range(len(dispatched))]
+    ref = BatchedServer(model, params, batch_size=B, max_seq=S, opts=OPTS,
+                        use_kernel=False)
+    assert out == ref.run(mk())
+    assert ref.kv_blocks == ref.kv_blocks_full == 0     # no kernel
+
+
 def test_fallback_family_serves_via_lockstep():
     model, params = _model("zamba2-7b")       # hybrid: no per-slot path
     srv = BatchedServer(model, params, batch_size=2, max_seq=64,

@@ -72,20 +72,26 @@ def _compiled_text(fn, args, sharding) -> str:
     return jax.jit(fn).lower(*sds).compile().as_text()
 
 
-# (B, Hq, Hkv, S, D, cache dtype)
+# (B, Hq, Hkv, S, D, cache dtype, precise), each at the block size the
+# kernel derives from these shapes, as served
 DECODE_CASES = {
     # qwen1.5-4b behind BatchedServer: 20 heads, MHA, f32 cache
-    "qwen1.5-4b": (4, 20, 20, 2048, 128, jnp.float32),
+    "qwen1.5-4b": (4, 20, 20, 2048, 128, jnp.float32, False),
+    # the benchmark's cells: qwen1.5-4b chat and summarize
+    "qwen1.5-4b.chat": (8, 20, 20, 512, 128, jnp.float32, False),
+    "qwen1.5-4b.summarize": (4, 20, 20, 1024, 128, jnp.float32, False),
+    # phi3.5-moe chat: GQA 4:1, float32 activations over bf16 weights
+    "phi3.5-moe.chat": (64, 32, 8, 512, 128, jnp.float32, True),
     # minitron-8b heads: 32 query heads over 8 KV heads (G=4)
-    "minitron-8b-gqa": (4, 32, 8, 2048, 128, jnp.bfloat16),
+    "minitron-8b-gqa": (4, 32, 8, 2048, 128, jnp.bfloat16, False),
 }
 
 
 @pytest.mark.parametrize("case", sorted(DECODE_CASES))
 def test_decode_attention_compiles(one_chip, case):
-    B, Hq, Hkv, S, D, dt = DECODE_CASES[case]
+    B, Hq, Hkv, S, D, dt, precise = DECODE_CASES[case]
     hlo = _compiled_text(
-        lambda q, k, v, n: decode_attention(q, k, v, n, bk=512,
+        lambda q, k, v, n: decode_attention(q, k, v, n, precise=precise,
                                             interpret=False),
         [((B, Hq, D), dt), ((B, Hkv, S, D), dt), ((B, Hkv, S, D), dt),
          ((B,), jnp.int32)], one_chip)

@@ -29,6 +29,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -58,6 +59,18 @@ def live_blocks(length, bk: int):
     for a sequence of length 0).  Host integers and arrays, or traced
     ones."""
     return -(-length // bk)
+
+
+def blocks_read(length, k):
+    """What a call reads of the cache ``k`` (anything with the cache's
+    ``shape`` (..., B, Hkv, S, D) and ``dtype``) for the B sequences'
+    ``length``: ``(read, full, bk)``, the blocks it reads
+    (``live_blocks``, summed over the sequences), the blocks a read of
+    every position takes (``B * S / bk``) and its block size
+    (``block_size``).  Host integers, for a counter."""
+    B, Hkv, S, D = k.shape[-4:]
+    bk = block_size(S, Hkv, D, jnp.dtype(k.dtype).itemsize)
+    return int(live_blocks(np.asarray(length), bk).sum()), B * (S // bk), bk
 
 
 def _dot(a, b, dims, precise: bool):

@@ -8,7 +8,6 @@ are the TPU-optimized equivalents and are validated against these.
 from __future__ import annotations
 
 import math
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -200,20 +199,14 @@ def _round_up(x: int, m: int) -> int:
 def decode_mha(
     q: jax.Array, k_cache: jax.Array, v_cache: jax.Array, ctx: ShardCtx, *,
     pos, is_global=True, window: int = 0,
-    k_new: Optional[jax.Array] = None, v_new: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """q: (B,1,Hq,D); caches: (B,Hkv,Sk,D); pos = current token position.
+    """q: (B,1,Hq,D); caches: (B,Hkv,Sk,D); attends positions <= ``pos``,
+    the current token's included.
 
     ``pos`` is either a scalar (lockstep batch: every sequence sits at the
     same position) or a ``(B,)`` vector of per-slot positions (continuous
     batching: each slot has its own occupancy).  The scalar case lowers to
-    a single broadcast mask row, so its numerics are unchanged.
-
-    When ``k_new/v_new`` are given, the caches are treated as holding only
-    positions < pos and the current token's K/V enter the softmax as one
-    extra slot (``k_new/v_new``: (B,Hkv,1,D)) — this keeps the cache
-    READ-ONLY inside scan-over-layers bodies (the cache is written after
-    the layer scan, in place, by ``write_rows``; see Model.decode_step).
+    a single broadcast mask row.
     """
     B, _, Hq, D = q.shape
     _, Hkv, Sk, _ = k_cache.shape
@@ -224,22 +217,12 @@ def decode_mha(
                    preferred_element_type=jnp.float32) * scale
     kpos = jnp.arange(Sk)
     posb = jnp.reshape(jnp.asarray(pos), (-1, 1))    # (1,1) | (B,1)
-    m = (kpos[None, :] < posb) if k_new is not None else (kpos[None, :] <= posb)
+    m = kpos[None, :] <= posb
     if window:
         m = m & ((kpos[None, :] > posb - window) | is_global)
     s = jnp.where(m[:, None, None, :], s, NEG_INF)
-    if k_new is not None:
-        s_self = jnp.einsum(
-            "bkgd,bksd->bkgs", qg, k_new.astype(q.dtype),
-            preferred_element_type=jnp.float32) * scale      # (B,Hkv,G,1)
-        s = jnp.concatenate([s, s_self], axis=-1)
     p = jax.nn.softmax(s, axis=-1).astype(v_cache.dtype)
-    if k_new is not None:
-        o = jnp.einsum("bkgs,bksd->bkgd", p[..., :-1], v_cache) + \
-            p[..., -1:] * v_new.astype(v_cache.dtype)
-        o = o.astype(v_cache.dtype)
-    else:
-        o = jnp.einsum("bkgs,bksd->bkgd", p, v_cache)
+    o = jnp.einsum("bkgs,bksd->bkgd", p, v_cache)
     return o.reshape(B, 1, Hq, D)
 
 
@@ -301,28 +284,33 @@ def cross_attention(
     return out_proj(p, o, cfg)
 
 
+def refuse_windowed_kernel(cfg: ArchConfig) -> None:
+    """Raise ``ValueError`` for a config with a sliding window: the
+    ``decode_attention`` kernel has no window mask."""
+    if cfg.sliding_window:
+        raise ValueError(
+            f"{cfg.name}: the decode_attention kernel has no sliding window "
+            "mask; decode windowed configs with use_kernel=False")
+
+
 def decode_self_attention(
     p, x: jax.Array, k_cache, v_cache, cfg: ArchConfig, ctx: ShardCtx, *,
-    pos, is_global=True, use_kernel: bool = False, layer=None,
+    pos, at, is_global=True, use_kernel: bool = False,
 ):
-    """One-token decode step against a head-major KV cache.
+    """One-token decode step against head-major KV-cache stacks.
 
-    ``pos`` is a scalar (lockstep) or ``(B,)`` per-slot positions
-    (continuous batching); rope is applied at each slot's own position.
-
-    Reference path (``use_kernel=False``): ``k_cache``/``v_cache`` are this
-    layer's read-only ``(B,Hkv,S,D)`` slabs; returns ``(out, k_row,
-    v_row)``, rows ``(B,Hkv,1,D)`` that the caller writes for all layers
-    after the layer scan.
-
-    Kernel path (``use_kernel=True``): ``k_cache``/``v_cache`` are the whole
-    ``(L,B,Hkv,S,D)`` stacks carried through the layer scan and ``layer``
-    is this layer's index.  Each slot's new row is written at its own
-    position in place, then the flash-decode Pallas kernel
-    (``repro.kernels.ops.decode_attention``) reads the layer's slab straight
-    out of the stack with per-slot ``length = pos + 1``; returns ``(out,
-    k_cache, v_cache)`` with the rows written.  Sliding-window configs must
-    stay on the reference path.
+    ``k_cache``/``v_cache`` are the whole ``(*lead, B, Hkv, S, D)`` stacks
+    that ride in the layer scan's carry, and ``at`` is this layer's leading
+    indices into them.  ``pos`` is a scalar (lockstep) or ``(B,)`` per-slot
+    positions (continuous batching); rope is applied at each slot's own
+    position.  Each slot's new K/V row is written in place at its position
+    (``write_rows``), then attention reads the layer's slab, the new token
+    included: with ``use_kernel`` the flash-decode Pallas kernel
+    (``repro.kernels.ops.decode_attention``) reads it straight out of the
+    stack with per-slot ``length = pos + 1`` (stacks with one leading axis,
+    no sliding window); otherwise ``decode_mha`` attends the slab's
+    positions ``<= pos``.  Returns ``(out, k_cache, v_cache)``, the stacks
+    with the rows written.
     """
     B = x.shape[0]
     q = project_q(p, x, cfg)                       # (B,1,Hq,D)
@@ -331,22 +319,18 @@ def decode_self_attention(
     q = rope(q, posv, cfg.rope_theta)
     k_new = rope(k_new, posv, cfg.rope_theta).transpose(0, 2, 1, 3)
     v_new = v_new.transpose(0, 2, 1, 3)            # (B,Hkv,1,D)
+    k_cache = write_rows(k_cache, k_new, pos, at=at)
+    v_cache = write_rows(v_cache, v_new, pos, at=at)
     if use_kernel:
-        if cfg.sliding_window:
-            raise ValueError(
-                "decode_attention kernel has no sliding-window mask; "
-                "keep use_kernel=False for windowed configs")
+        refuse_windowed_kernel(cfg)
         from repro.kernels import ops as kernel_ops
-        k_cache = write_rows(k_cache, k_new, pos, at=(layer,))
-        v_cache = write_rows(v_cache, v_new, pos, at=(layer,))
+        (layer,) = at
         o = kernel_ops.decode_attention(
             q.astype(k_cache.dtype).reshape(B, cfg.n_heads, cfg.head_dim),
             k_cache, v_cache, posv[:, 0].astype(jnp.int32) + 1, layer,
             precise=twopart.wider(x.dtype, cfg.dtype))
         o = o.reshape(B, 1, cfg.n_heads, cfg.head_dim)
-        return out_proj(p, o.astype(x.dtype), cfg), k_cache, v_cache
-    o = decode_mha(q, k_cache, v_cache, ctx, pos=pos,
-                   is_global=is_global, window=cfg.sliding_window,
-                   k_new=k_new, v_new=v_new)
-    return (out_proj(p, o.astype(x.dtype), cfg),
-            k_new.astype(k_cache.dtype), v_new.astype(v_cache.dtype))
+    else:
+        o = decode_mha(q, k_cache[at], v_cache[at], ctx, pos=pos,
+                       is_global=is_global, window=cfg.sliding_window)
+    return out_proj(p, o.astype(x.dtype), cfg), k_cache, v_cache

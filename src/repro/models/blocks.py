@@ -2,7 +2,6 @@
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -72,34 +71,34 @@ def dense_block(p, h, cfg: ArchConfig, ctx: ShardCtx, opts: ModelOpts, *,
 
 
 def dense_block_decode(p, h, k_cache, v_cache, cfg: ArchConfig,
-                       ctx: ShardCtx, *, pos, is_global=True,
-                       use_kernel: bool = False, layer=None, experts=None):
-    """One-token step.  Returns (h, k, v, held).
+                       ctx: ShardCtx, *, pos, at, is_global=True,
+                       use_kernel: bool = False, experts=None):
+    """One-token step.  Returns (h, k_cache, v_cache, held).
 
-    ``pos`` may be scalar (lockstep) or ``(B,)`` per-slot positions.  On
-    the reference path the caches are this layer's read-only slabs and
-    ``k, v`` its new rows; with ``use_kernel`` they are the whole stacks,
-    ``layer`` indexes them, and ``k, v`` are the stacks with this layer's
-    rows written in place (``attention.decode_self_attention``).
+    ``k_cache``/``v_cache`` are the whole KV stacks and ``at`` this layer's
+    leading indices into them; they come back with this layer's rows
+    written in place (``attention.decode_self_attention``).  ``pos`` may be
+    scalar (lockstep) or ``(B,)`` per-slot positions.
 
     An MoE layer is the dropless held-experts layer
-    (``moe.held_experts_ffn``); with ``use_kernel`` its expert weights are
-    ``experts``, the stacks of every layer, read at ``layer``.  ``held`` is
-    each token's held-expert bits, ``(B, 1)`` int32; None for a dense layer.
+    (``moe.held_experts_ffn``): its expert weights are ``experts``, the
+    stacks of every layer, read at ``at[0]``.  ``held`` is each token's
+    held-expert bits, ``(B, 1)`` int32; None for a dense layer.
+    ``use_kernel`` computes both with the Pallas kernels.
     """
-    a, k_new, v_new = attn.decode_self_attention(
+    a, k_cache, v_cache = attn.decode_self_attention(
         p["attn"], norm(p["ln1"], h, cfg), k_cache, v_cache, cfg, ctx,
-        pos=pos, is_global=is_global, use_kernel=use_kernel, layer=layer)
+        pos=pos, at=at, is_global=is_global, use_kernel=use_kernel)
     h = h + a
     hn = norm(p["ln2"], h, cfg)
     held = None
     if cfg.n_experts:
         f, held = moe_mod.held_experts_ffn(
-            p["moe"], hn, cfg, experts=experts, layer=layer,
+            p["moe"], hn, cfg, experts=experts, layer=at[0],
             use_kernel=use_kernel)
     else:
         f = mlp(p["mlp"], hn, cfg, ctx)
-    return h + f, k_new, v_new, held
+    return h + f, k_cache, v_cache, held
 
 
 # ---------------------------------------------------------------------------

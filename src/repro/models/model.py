@@ -366,17 +366,16 @@ class Model:
         recurrent state) families.
 
         K/V caches are head-major, ``(..., B, Hkv, S, D)`` (``init_cache``),
-        and are read and written where they lie: each new row is an in-place
-        ``dynamic_update_slice`` (``attention.write_rows``).  With
-        ``opts.use_kernel`` (dense/moe) the caches ride in the layer scan's
-        carry, each layer writes its rows before the kernel reads its slab
-        out of the stack; otherwise every layer reads its slab read-only and
-        the rows are written after the scan.
-
-        An MoE layer is the dropless held-experts layer
-        (``moe.held_experts_ffn``).  On the kernel path the expert weights
-        stay out of the layer scan's sliced inputs: the ``expert_ffn``
-        kernel reads each layer's experts out of the whole stacks.
+        and are read and written where they lie: the whole stacks ride in
+        the layer scan's carry (for hybrid, the group scan's; for vlm, both
+        scans'), each layer writes its slots' rows in place
+        (``attention.write_rows``) and then attention reads its slab at the
+        layer's index.  An MoE layer is the dropless held-experts layer
+        (``moe.held_experts_ffn``); its expert stacks stay out of the layer
+        scan's sliced inputs and each layer reads its own at its index.
+        ``opts.use_kernel`` picks the Pallas kernels (``decode_attention``,
+        ``expert_ffn``) over plain jnp at those two points of the dense/moe
+        families; nothing else differs.
 
         Traced under the name scope ``decode_step``, so that its operations
         carry that name in a profile.
@@ -398,21 +397,20 @@ class Model:
         h = ctx.constrain(h, "batch", "seq", "act_embed")
 
         stats: Dict[str, Any] = {}
-        if cfg.family in ("dense", "moe") and opts.use_kernel:
+        if cfg.family in ("dense", "moe"):
             layers, experts = params["layers"], None
             if cfg.n_experts:
                 moe = layers["moe"]
                 experts = {k: moe[k] for k in ("wg", "wi", "wo")}
                 layers = dict(layers, moe={"router": moe["router"]})
 
-            # the whole caches ride in the carry: each layer writes its rows
-            # in place and the kernel reads its slab out of the stack
             def body(carry, xs):
                 hh, kc, vc = carry
                 p_i, flag, layer = xs
                 hh, kc, vc, held = B.dense_block_decode(
-                    p_i, hh, kc, vc, cfg, ctx, pos=pos, is_global=flag,
-                    use_kernel=True, layer=layer, experts=experts)
+                    p_i, hh, kc, vc, cfg, ctx, pos=pos, at=(layer,),
+                    is_global=flag, use_kernel=opts.use_kernel,
+                    experts=experts)
                 return (hh, kc, vc), held
 
             (h, kc, vc), held = jax.lax.scan(
@@ -420,20 +418,6 @@ class Model:
                 (layers, jnp.asarray(self.global_flags()),
                  jnp.arange(cfg.n_layers, dtype=jnp.int32)))
             cache = {"k": kc, "v": vc}
-
-        elif cfg.family in ("dense", "moe"):
-            def body(hh, xs):
-                p_i, flag, kc, vc = xs
-                hh, kn, vn, held = B.dense_block_decode(
-                    p_i, hh, kc, vc, cfg, ctx, pos=pos, is_global=flag)
-                return hh, (kn, vn, held)
-
-            h, (kns, vns, held) = jax.lax.scan(
-                body, h, (params["layers"], jnp.asarray(self.global_flags()),
-                          cache["k"], cache["v"]))
-            # every layer's rows written after the scan, in place
-            cache = {"k": attn_mod.write_rows(cache["k"], kns, pos),
-                     "v": attn_mod.write_rows(cache["v"], vns, pos)}
 
         elif cfg.family == "ssm":
             def body(hh, xs):
@@ -452,29 +436,27 @@ class Model:
                     "per-slot decode positions: hybrid family serves via "
                     "the lockstep path")
             shared = params["shared"]
+            g, _, _ = _groups(cfg)
 
             def inner(hh, xs):
                 p_i, c = xs
                 hh, c = B.mamba_block_decode(p_i, hh, c, cfg, ctx)
                 return hh, c
 
-            def group(hh, xs):
-                p_g, cg, kc, vc = xs
+            def group(carry, xs):
+                hh, kc, vc = carry
+                p_g, cg, gi = xs
                 hh, cg = jax.lax.scan(inner, hh, (p_g, cg))
-                hh, kn, vn, _ = B.dense_block_decode(
-                    shared, hh, kc, vc, cfg, ctx, pos=pos)
-                return hh, (cg, kn, vn)
+                hh, kc, vc, _ = B.dense_block_decode(
+                    shared, hh, kc, vc, cfg, ctx, pos=pos, at=(gi,))
+                return (hh, kc, vc), cg
 
-            h, (cg, kns, vns) = jax.lax.scan(
-                group, h,
+            (h, kc, vc), cg = jax.lax.scan(
+                group, (h, cache["k"], cache["v"]),
                 (params["groups"],
                  {"ssm": cache["ssm"], "conv": cache["conv"]},
-                 cache["k"], cache["v"]))
-            new = {
-                "ssm": cg["ssm"], "conv": cg["conv"],
-                "k": attn_mod.write_rows(cache["k"], kns, pos),
-                "v": attn_mod.write_rows(cache["v"], vns, pos),
-            }
+                 jnp.arange(g, dtype=jnp.int32)))
+            new = {"ssm": cg["ssm"], "conv": cg["conv"], "k": kc, "v": vc}
             if "rem" in params:
                 h, rc = jax.lax.scan(
                     inner, h,
@@ -488,27 +470,28 @@ class Model:
                 raise NotImplementedError(
                     "per-slot decode positions: vlm family serves via "
                     "the lockstep path")
+            g, k, _ = _groups(cfg)
 
-            def inner(hh, xs):
-                p_i, kc, vc = xs
-                hh, kn, vn, _ = B.dense_block_decode(
-                    p_i, hh, kc, vc, cfg, ctx, pos=pos)
-                return hh, (kn, vn)
+            def group(carry, xs):
+                p_self, p_cross, xk, xv, gi = xs
 
-            def group(hh, xs):
-                p_self, p_cross, kc, vc, xk, xv = xs
-                hh, (kn, vn) = jax.lax.scan(inner, hh, (p_self, kc, vc))
+                def inner(carry, xs):
+                    hh, kc, vc = carry
+                    p_i, j = xs
+                    hh, kc, vc, _ = B.dense_block_decode(
+                        p_i, hh, kc, vc, cfg, ctx, pos=pos, at=(gi, j))
+                    return (hh, kc, vc), None
+
+                (hh, kc, vc), _ = jax.lax.scan(
+                    inner, carry, (p_self, jnp.arange(k, dtype=jnp.int32)))
                 hh = B.cross_block_cached(p_cross, hh, xk, xv, cfg, ctx)
-                return hh, (kn, vn)
+                return (hh, kc, vc), None
 
-            h, (kns, vns) = jax.lax.scan(
-                group, h,
-                (params["self"], params["cross"], cache["k"], cache["v"],
-                 cache["xk"], cache["xv"]))
-            cache = {
-                "k": attn_mod.write_rows(cache["k"], kns, pos),
-                "v": attn_mod.write_rows(cache["v"], vns, pos),
-                "xk": cache["xk"], "xv": cache["xv"]}
+            (h, kc, vc), _ = jax.lax.scan(
+                group, (h, cache["k"], cache["v"]),
+                (params["self"], params["cross"], cache["xk"], cache["xv"],
+                 jnp.arange(g, dtype=jnp.int32)))
+            cache = {"k": kc, "v": vc, "xk": cache["xk"], "xv": cache["xv"]}
         else:
             raise ValueError(f"{cfg.family} has no decode step")
 

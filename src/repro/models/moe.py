@@ -204,9 +204,9 @@ def held_experts_ffn(p, x: jax.Array, cfg: ArchConfig, *, experts=None,
     their weights' dtype, at float32 precision where x is float32 and the
     weights narrower (``twopart``); ``p`` holds this layer's ``router`` (D,
     n_experts) and, unless ``experts`` is given, its held experts' ``wg``,
-    ``wi`` (E_held, D, F) and ``wo`` (E_held, F, D).  With ``use_kernel``
-    the ``expert_ffn`` Pallas kernel computes them, reading ``experts``,
-    the (L, E_held, ...) stacks of every layer, at ``layer`` where they
+    ``wi`` (E_held, D, F) and ``wo`` (E_held, F, D).  ``experts`` are the
+    (L, E_held, ...) stacks of every layer, read at ``layer``; with
+    ``use_kernel`` the ``expert_ffn`` Pallas kernel reads them where they
     lie.  Every token goes through every held expert, weighted by its gate,
     so the work does not depend on the routing.  -> (y (..., D) in x's
     dtype, held bits (...,) int32: the held experts each token was routed
@@ -217,13 +217,15 @@ def held_experts_ffn(p, x: jax.Array, cfg: ArchConfig, *, experts=None,
     lead, D = x.shape[:-1], x.shape[-1]
     xt = x.reshape(-1, D)
     gates, bits = held_gates(*route(p["router"], xt, cfg), cfg)
-    w = experts if experts is not None else p
+    if experts is None:                  # this layer's own: a stack of one
+        experts, layer = {k: p[k][None] for k in ("wg", "wi", "wo")}, 0
+    wg, wi, wo = experts["wg"], experts["wi"], experts["wo"]
     if use_kernel:
         from repro.kernels import ops as kernel_ops
-        y = kernel_ops.expert_ffn(xt, gates, w["wg"], w["wi"], w["wo"],
-                                  0 if layer is None else layer)
+        y = kernel_ops.expert_ffn(xt, gates, wg, wi, wo, layer)
     else:
-        dt = w["wg"].dtype
+        wg, wi, wo = wg[layer], wi[layer], wo[layer]
+        dt = wg.dtype
         split = twopart.wider(xt.dtype, dt)
 
         def dot(v, m):                 # (n, k) @ (e, k, f) per expert
@@ -232,11 +234,10 @@ def held_experts_ffn(p, x: jax.Array, cfg: ArchConfig, *, experts=None,
             return jnp.einsum("nk,ekf->enf", v.astype(dt), m,
                               preferred_element_type=jnp.float32)
 
-        h = jax.nn.silu(dot(xt, w["wg"])) * dot(xt, w["wi"]) * \
-            gates.T[..., None]
+        h = jax.nn.silu(dot(xt, wg)) * dot(xt, wi) * gates.T[..., None]
         if split:
-            y = jax.vmap(twopart.matmul)(h, w["wo"]).sum(0)
+            y = jax.vmap(twopart.matmul)(h, wo).sum(0)
         else:
-            y = jnp.einsum("enf,efd->nd", h.astype(dt), w["wo"],
+            y = jnp.einsum("enf,efd->nd", h.astype(dt), wo,
                            preferred_element_type=jnp.float32)
     return y.astype(x.dtype).reshape(x.shape), bits.reshape(lead)

@@ -24,7 +24,8 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.distrib.logical import NOSHARD
-from repro.kernels.decode_attention import block_size, live_blocks
+from repro.kernels.decode_attention import blocks_read
+from repro.models.attention import refuse_windowed_kernel
 from repro.models.blocks import ModelOpts
 from repro.models.model import Model
 
@@ -138,10 +139,12 @@ class BatchedServer:
     (the per-slot mask rows and rope positions coincide with the shared
     position, and the argmax over identical logits is deterministic).
 
-    ``use_kernel=True`` puts the flash-decode Pallas kernel on the
-    generation path with per-slot ``length`` (greedy tokens are validated
-    against the reference path).  Only dense/moe configs without a sliding
-    window can take it; any other config refuses it with ``ValueError``.
+    ``use_kernel=True`` puts the Pallas kernels on the decode step: the
+    flash-decode kernel with per-slot ``length`` and, for an MoE model,
+    the ``expert_ffn`` kernel (greedy tokens are validated against the jnp
+    path).  This keyword, not ``opts.use_kernel``, decides the server's
+    decode step.  Only dense/moe configs without a sliding window can take
+    it; any other config refuses it with ``ValueError``.
     The decode step donates the KV cache, so one copy is live per step.
     Families with per-slot support: dense / moe (KV caches) and ssm
     (position-free recurrent state, reset per slot on admission);
@@ -187,7 +190,7 @@ class BatchedServer:
     def __init__(self, model: Model, params, *, batch_size: int = 4,
                  max_seq: int = 256, opts: ModelOpts = ModelOpts(),
                  eos_id: Optional[int] = None,
-                 use_kernel: Optional[bool] = None):
+                 use_kernel: bool = False):
         self.model = model
         self.params = params
         self.B = batch_size
@@ -196,19 +199,19 @@ class BatchedServer:
         self.eos_id = eos_id
         cfg = model.cfg
         self.continuous = cfg.family in self.SLOT_FAMILIES
-        if use_kernel is None:
-            use_kernel = opts.use_kernel
-        if use_kernel and (cfg.family not in ("dense", "moe")
-                           or cfg.sliding_window):
-            raise ValueError(
-                f"{cfg.name}: the decode_attention kernel serves dense/moe "
-                "configs without a sliding window; use use_kernel=False")
-        self.use_kernel = bool(use_kernel)
+        if use_kernel:
+            if cfg.family not in ("dense", "moe"):
+                raise ValueError(
+                    f"{cfg.name}: the decode kernels serve the dense/moe "
+                    "families; use use_kernel=False")
+            refuse_windowed_kernel(cfg)
+        self.use_kernel = use_kernel
+        dopts = dataclasses.replace(opts, use_kernel=use_kernel)
         self._lockstep: Optional[LockstepServer] = None
         if not self.continuous:
             self._lockstep = LockstepServer(
                 model, params, batch_size=batch_size, max_seq=max_seq,
-                opts=opts, eos_id=eos_id)
+                opts=dopts, eos_id=eos_id)
             return
         self.cache = model.init_cache(batch_size, max_seq, jnp.float32)
         self.steps = 0                     # completed decode steps
@@ -220,17 +223,14 @@ class BatchedServer:
         self.expert_loads = 0              # (layer, held expert) pairs used
         self.kv_blocks = 0                 # KV blocks the kernel reads
         self.kv_blocks_full = 0            # ... if it read every position
-        if self.use_kernel:
-            k = self.cache["k"]            # (L, B, Hkv, S, D)
-            self._bk = block_size(max_seq, k.shape[2], k.shape[4],
-                                  k.dtype.itemsize)
         self.queue: List[Request] = []
         self.active: List[Optional[Request]] = [None] * self.B
         self.results: Dict[int, List[int]] = {}
         self._cursor = np.zeros(self.B, np.int64)   # per-slot prompt cursor
         self._token = np.zeros((self.B, 1), np.int32)
         self._pos = np.zeros(self.B, np.int32)      # per-slot position
-        dopts = dataclasses.replace(opts, use_kernel=self.use_kernel)
+        if use_kernel:                     # the kernel's block size here
+            *_, self._bk = blocks_read(self._pos + 1, self.cache["k"])
 
         def decode_step(p, t, pos, c):
             return model.decode_step(p, {"token": t, "pos": pos}, c,
@@ -282,9 +282,9 @@ class BatchedServer:
                     self.prompt_tokens += int(self._cursor[i] < len(r.prompt))
             self.slot_steps += len(occupied)
             if self.use_kernel:
-                self.kv_blocks += int(live_blocks(self._pos + 1,
-                                                  self._bk).sum())
-                self.kv_blocks_full += self.B * (self.S // self._bk)
+                read, full, _ = blocks_read(self._pos + 1, self.cache["k"])
+                self.kv_blocks += read
+                self.kv_blocks_full += full
             with TraceAnnotation("serve.dispatch"):
                 logits, self.cache, *counts = self._decode(
                     self.params, jnp.asarray(self._token),

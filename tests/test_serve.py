@@ -49,6 +49,11 @@ def dense():
 
 
 @pytest.fixture(scope="module")
+def moe():
+    return _model("phi3.5-moe-42b-a6.6b")
+
+
+@pytest.fixture(scope="module")
 def ssm():
     return _model("mamba2-130m")
 
@@ -115,13 +120,19 @@ def test_kernel_path_matches_reference(dense):
     assert [len(out[i]) for i in (10, 11)] == [7, 9]
 
 
-@pytest.mark.parametrize("use_kernel", (False, True))
-def test_prefill_cache_feeds_decode_step(dense, use_kernel):
+# (fixture, use_kernel): qwen's cases keep their ids; Phi-3.5-MoE's read
+# the expert stacks at each layer's index on both paths
+PREFILL_CASES = [pytest.param("dense", k, id=str(k)) for k in (False, True)] \
+    + [pytest.param("moe", k, id=f"phi3.5-moe-{k}") for k in (False, True)]
+
+
+@pytest.mark.parametrize("fixture,use_kernel", PREFILL_CASES)
+def test_prefill_cache_feeds_decode_step(fixture, use_kernel, request):
     """``Model.prefill`` emits the head-major cache that ``decode_step``
     reads: padded along its position axis, it continues the sequence (at
     per-slot positions) as the full prefill does, and it holds the rows
     that decoding the same tokens one at a time (lockstep) writes."""
-    model, params = dense
+    model, params = request.getfixturevalue(fixture)
     n, S = 11, 16
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, n + 1), 0,
                               model.cfg.vocab)
@@ -142,6 +153,47 @@ def test_prefill_cache_feeds_decode_step(dense, use_kernel):
             params, {"token": toks[:, i:i + 1], "pos": jnp.int32(i)}, dec,
             opts=opts)
     for key in ("k", "v"):
+        np.testing.assert_allclose(
+            np.asarray(dec[key][..., :n, :]), np.asarray(pc[key]),
+            rtol=1e-2, atol=1e-2)
+        assert not np.asarray(dec[key][..., n:, :]).any()
+
+
+# reduced vlm groups hold one self-attention layer each: two, here, so the
+# (group, layer) index takes both its parts
+GROUPED = {"zamba2-7b": {},
+           "llama-3.2-vision-90b": dict(n_layers=6, cross_attn_every=3)}
+
+
+@pytest.mark.parametrize("arch", sorted(GROUPED))
+def test_grouped_decode_writes_the_rows_prefill_emits(arch):
+    """The hybrid and vlm decode steps carry their KV stacks through the
+    group scans and write each token's rows in place, at the group's index
+    (hybrid's shared attention block) and at (group, layer) (vlm's self
+    attention): decoding a sequence one token at a time at a scalar
+    position ends at the logits ``Model.prefill`` gives and holds the K/V
+    rows it emits, and nothing past them."""
+    cfg = dataclasses.replace(REGISTRY[arch].reduced(), **GROUPED[arch])
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    n, S = 8, 16
+    toks = jax.random.randint(jax.random.PRNGKey(1), (2, n), 0, cfg.vocab)
+    batch = {"tokens": toks}
+    if cfg.family == "vlm":
+        batch["image_embeds"] = jax.random.normal(
+            jax.random.PRNGKey(2), (2, cfg.n_image_tokens, cfg.d_model))
+    want, pc = model.prefill(params, batch, opts=OPTS)
+    dec = model.init_cache(2, S, jnp.float32)
+    if cfg.family == "vlm":             # the image's K/V, as prefill cached
+        dec.update(xk=pc["xk"], xv=pc["xv"])
+    for i in range(n):
+        lg, dec = model.decode_step(
+            params, {"token": toks[:, i:i + 1], "pos": jnp.int32(i)}, dec,
+            opts=OPTS)
+    np.testing.assert_allclose(np.asarray(lg), np.asarray(want),
+                               rtol=1e-2, atol=1e-2)
+    for key in ("k", "v"):
+        assert dec[key].shape[:-2] == pc[key].shape[:-2]
         np.testing.assert_allclose(
             np.asarray(dec[key][..., :n, :]), np.asarray(pc[key]),
             rtol=1e-2, atol=1e-2)

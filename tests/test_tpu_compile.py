@@ -183,20 +183,28 @@ def _instructions(hlo: str):
     return [m for m in map(_INSTR.match, hlo.splitlines()) if m]
 
 
-# instruction lines of the served qwen1.5-4b step at 2 layers, as compiled
-# before the MoE layer and LayerNorm came in: shared code left it unchanged
-QWEN_INSTRUCTIONS = {"chat": 699, "summarize": 632}
+# the served step in each benchmark cell at 2 layers: (arch, batch,
+# max_seq, held experts) and its instruction lines as compiled; the kernel
+# path these cells run does not change with code that only other paths use
+SERVED_STEPS = {
+    "chat": ("qwen1.5-4b", 8, 512, None, 699),
+    "summarize": ("qwen1.5-4b", 4, 1024, None, 632),
+    "phi3.5-moe.chat": ("phi3.5-moe-42b-a6.6b", 64, 512, (0, 8), 2136),
+}
 
 
-@pytest.mark.parametrize("cell", sorted(SERVED))
+@pytest.mark.parametrize("cell", sorted(SERVED_STEPS))
 def test_served_qwen_decode_step_unchanged(one_chip, monkeypatch, cell):
+    """The served decode step of each benchmark cell, qwen1.5-4b's and
+    Phi-3.5-MoE's, compiles to the instruction count pinned for it."""
     from repro.models.model import build_model
     monkeypatch.setattr(kernel_ops, "_default_interpret", lambda: False)
-    B, S = SERVED[cell]
-    model = build_model(dataclasses.replace(REGISTRY["qwen1.5-4b"],
-                                            n_layers=2))
-    hlo = _served_step_text(model, B, S, one_chip)
-    assert len(_instructions(hlo)) == QWEN_INSTRUCTIONS[cell]
+    arch, B, S, held, instructions = SERVED_STEPS[cell]
+    cfg = dataclasses.replace(REGISTRY[arch], n_layers=2)
+    if held:
+        cfg = dataclasses.replace(cfg, held_experts=held)
+    hlo = _served_step_text(build_model(cfg), B, S, one_chip)
+    assert len(_instructions(hlo)) == instructions
 
 
 def test_served_phi_moe_decode_step_compiles(one_chip, monkeypatch):
